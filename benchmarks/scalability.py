@@ -4,10 +4,9 @@ Three sweeps:
 
 * horizontal / vertical (vmap lanes, as before): fixed data over more
   shards; fixed shards over more data.
-* **mesh sweep** (the Fig-6 shape): the shard_map backend on a real
-  multi-device host mesh (``XLA_FLAGS=
-  --xla_force_host_platform_device_count=8``), 1/2/4/8 devices, timing
-  the broadcast point lookup against ``lookup_routed`` at large Q.
+* **mesh sweep** (the Fig-6 shape): the shard_map backend over 1/2/4 of
+  ``jax.devices()``, timing the broadcast point lookup against
+  ``lookup_routed`` at large Q.
   Broadcast probes every query on every device (s×Q lanes); routing
   probes each query once on its owner plus two all-to-alls (~2Q lanes at
   the 2x-overprovisioned capacity) — the s× redundancy the ROADMAP
@@ -16,20 +15,20 @@ Three sweeps:
 * **skew sweep** (ISSUE 9): Zipf-distributed data and queries at
   s in {0.5, 1.0, 1.5}, pure routing at the standard 2x exchange
   capacity vs the hot-key-replicated hybrid (DESIGN.md §15) — on the
-  vmap-4 backend in this process and on the forced-8 shard_map mesh in
-  the subprocess.  The headline: at s=1.5 routing drops and needs
-  capacity-doubling retries to deliver; the hybrid stays flat at zero
-  drops, bit-identical to the full-capacity routed oracle.
+  vmap-4 backend and on the 4-device shard_map mesh.  The headline: at
+  s=1.5 routing drops and needs capacity-doubling retries to deliver;
+  the hybrid stays flat at zero drops, bit-identical to the
+  full-capacity routed oracle.
 
-The mesh sweep needs the forced device count set *before* jax
-initializes, so it runs in a subprocess (``--mesh-worker``); the parent
-collects its JSON and lands everything in ``BENCH_scale.json`` at the
+Everything runs in this process.  The mesh sweeps need 4 devices: on
+the CPU the caller provides them before JAX starts
+(``XLA_FLAGS=--xla_force_host_platform_device_count=4``, as
+``scripts/ci.sh`` does).  Results land in ``BENCH_scale.json`` at the
 repo root (the committed artifact) as well as the harness report.
 """
 
 import json
 import os
-import subprocess
 import sys
 
 import jax
@@ -40,7 +39,7 @@ from repro.core.planner import Planner
 from benchmarks.common import Report, powerlaw_keys, timeit
 
 SCH = Schema.of("k", k="int64", v="float32")
-MESH_DEVICES = (1, 2, 4, 8)
+MESH_DEVICES = (1, 2, 4)               # up to the 4 chips of one v5e host
 ZIPF_S = (0.5, 1.0, 1.5)
 
 
@@ -166,15 +165,13 @@ def _vmap_sweeps(rep, rng, n):
         rep.add(f"vertical n={nn}", ms=t * 1e3)
 
 
-def _mesh_worker(quick: bool):
-    """Runs inside the forced-8-device subprocess (XLA_FLAGS is set in
-    the child's env before python starts, so the module-level jax import
-    already sees 8 devices): shard_map backend, broadcast vs routed
-    point lookups per device count."""
+def _mesh_sweep(rep, quick: bool):
+    """shard_map backend over 1/2/4 of ``jax.devices()``: broadcast vs
+    routed point lookups per device count, then the skew sweep on the
+    widest mesh.  Returns ``(mesh_rows, skew_rows)``."""
     from repro import dist
     from repro.dist import mesh
 
-    assert len(jax.devices()) >= max(MESH_DEVICES), jax.devices()
     sch = SCH
     rng = np.random.default_rng(7)
     n = 60_000 if quick else 400_000
@@ -217,39 +214,14 @@ def _mesh_worker(quick: bool):
                      "planner": ("routed" if phys.kind == "RoutedLookup"
                                  else "bcast"),
                      "planner_rule": phys.reason})
-    print("MESH_SWEEP_JSON " + json.dumps(rows), flush=True)
-    skew = _skew_rows(max(MESH_DEVICES), mesh.mesh_runtime(max(MESH_DEVICES)),
-                      quick, f"shard_map-{max(MESH_DEVICES)}")
-    print("SKEW_SWEEP_JSON " + json.dumps(skew), flush=True)
-
-
-def _mesh_sweep(rep, quick: bool):
-    """Spawn the forced-device subprocess and fold its rows in."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count="
-                          f"{max(MESH_DEVICES)}").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, "-m", "benchmarks.scalability", "--mesh-worker"]
-    if not quick:
-        cmd.append("--full")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          cwd=root, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(f"mesh worker failed:\n{proc.stdout}\n"
-                           f"{proc.stderr}")
-    def grab(tag):
-        line = [ln for ln in proc.stdout.splitlines()
-                if ln.startswith(tag + " ")][-1]
-        return json.loads(line[len(tag) + 1:])
-
-    rows = grab("MESH_SWEEP_JSON")
     for r in rows:
         rep.add(r["label"], bcast_ms=r["bcast_ms"],
                 routed_ms=r["routed_ms"],
                 routed_speedup=r["routed_speedup"],
                 routed_dropped=r["routed_dropped"])
-    skew_rows = grab("SKEW_SWEEP_JSON")
+    skew_rows = _skew_rows(max(MESH_DEVICES),
+                           mesh.mesh_runtime(max(MESH_DEVICES)), quick,
+                           f"shard_map-{max(MESH_DEVICES)}")
     return rows, skew_rows
 
 
@@ -287,7 +259,4 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
-    if "--mesh-worker" in sys.argv:
-        _mesh_worker(quick="--full" not in sys.argv)
-    else:
-        run(quick="--full" not in sys.argv)
+    run(quick="--full" not in sys.argv)

@@ -13,12 +13,13 @@ ring.  Per grid cell:
 * MEASURED host syncs per tick (``common.SyncCounter``), trace counts,
   flushes, pad overhead.
 
-Cells run on the vmap emulation backend in-process and on the REAL
-shard_map backend under a forced 8-device host mesh (subprocess worker,
-same idiom as ``benchmarks.scalability``), plus one supervised cell
-where a seeded shard kill lands mid-run and the engine serves through
-the heal.  Every cell's answers are verified bit-identical to an
-unbatched MVCC twin replaying the engine's ``write_log``
+Cells run on the vmap emulation backend and on the REAL shard_map
+backend over 4 of ``jax.devices()`` (on the CPU the caller provides them:
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``), all in this
+process, plus one supervised cell where a seeded shard kill lands
+mid-run and the engine serves through the heal.  Every cell's answers
+are verified bit-identical to an unbatched MVCC twin replaying the
+engine's ``write_log``
 (``replay_unbatched``); the committed summary asserts
 ``zero_retraces_after_warmup`` and ``batched_equals_unbatched`` under
 BOTH topologies.
@@ -29,7 +30,6 @@ Results -> ``BENCH_serve.json`` at the repo root.
 import dataclasses
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -49,7 +49,7 @@ ARTIFACT = os.path.join(os.path.dirname(os.path.dirname(
 N_ROWS = 4096
 LADDER = (8, 16, 32)
 SIZES = (1, 4, 8, 9, 16, 32)          # request sizes, boundary-heavy
-MESH_DEVICES = 8
+MESH_SHARDS = 4                       # the shard_map grid: one v5e host
 
 
 def _build(num_shards, rt, rng):
@@ -215,40 +215,12 @@ def _grid(backend, num_shards, rt, *, quick: bool, seed0: int = 31):
     return rows
 
 
-def _mesh_worker(quick: bool):
-    """Runs under XLA_FLAGS=--xla_force_host_platform_device_count=8:
-    the grid on the REAL shard_map backend."""
-    import jax
-    assert len(jax.devices()) >= MESH_DEVICES, jax.devices()
-    rt = mesh.mesh_runtime(MESH_DEVICES)
-    rows = _grid("shard_map", MESH_DEVICES, rt, quick=quick, seed0=57)
-    print("SERVE_MESH_JSON " + json.dumps(rows), flush=True)
-
-
-def _mesh_grid(quick: bool):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count="
-                          f"{MESH_DEVICES}").strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    cmd = [sys.executable, "-m", "benchmarks.serve", "--mesh-worker"]
-    if not quick:
-        cmd.append("--full")
-    proc = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                          cwd=root, timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(f"serve mesh worker failed:\n{proc.stdout}\n"
-                           f"{proc.stderr}")
-    line = [ln for ln in proc.stdout.splitlines()
-            if ln.startswith("SERVE_MESH_JSON ")][-1]
-    return json.loads(line[len("SERVE_MESH_JSON "):])
-
-
 def run(quick: bool = True):
     rng_label = "quick" if quick else "full"
     rep = Report("serve")
     rows = _grid("vmap", 4, mesh.vmap_runtime(), quick=quick)
-    rows += _mesh_grid(quick)
+    rows += _grid("shard_map", MESH_SHARDS, mesh.mesh_runtime(MESH_SHARDS),
+                  quick=quick, seed0=57)
     rows.append(_supervised_cell(4, mesh.vmap_runtime(),
                                  requests=48 if quick else 192, seed=91))
     for r in rows:
@@ -285,7 +257,4 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
-    if "--mesh-worker" in sys.argv:
-        _mesh_worker(quick="--full" not in sys.argv)
-    else:
-        run(quick="--full" not in sys.argv)
+    run(quick="--full" not in sys.argv)

@@ -72,7 +72,8 @@ if [[ "${1:-}" != "--fast" ]]; then
   echo "== quick benchmarks =="
   python -m benchmarks.run --only lookup_path --out /tmp/ci_bench_lookup.json
   python -m benchmarks.run --only fault_tolerance --out /tmp/ci_bench_dist.json
-  python -m benchmarks.run --only scalability --out /tmp/ci_bench_scale.json
+  XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=4" \
+    python -m benchmarks.run --only scalability --out /tmp/ci_bench_scale.json
 fi
 
 echo "CI gate OK"

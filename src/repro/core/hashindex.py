@@ -74,8 +74,10 @@ def _segment_rank(sorted_ids):
     idx = jnp.arange(n, dtype=jnp.int32)
     is_start = jnp.concatenate(
         [jnp.ones((1,), bool), sorted_ids[1:] != sorted_ids[:-1]])
-    start_pos = jax.lax.associative_scan(jnp.maximum,
-                                         jnp.where(is_start, idx, -1))
+    # cummax, not associative_scan: the TPU lowering is a native scan,
+    # where an unrolled associative_scan at 2^24 elements takes the TPU
+    # compiler minutes.
+    start_pos = jax.lax.cummax(jnp.where(is_start, idx, -1))
     return idx - start_pos
 
 
@@ -116,16 +118,16 @@ def _build_arrays(keys, row_ids, valid, num_buckets: int, slots: int):
     rank = _segment_rank(b2)
     overflow = jnp.sum((rank >= slots) & head2)
     ok = head2 & (rank < slots)
-    flat = jnp.where(ok, b2 * slots + jnp.minimum(rank, slots - 1),
-                     jnp.int32(num_buckets * slots))  # out-of-range = drop
-
-    bucket_keys = jnp.full((num_buckets * slots,), EMPTY_KEY, jnp.int64)
-    bucket_ptrs = jnp.full((num_buckets * slots,), NULL_PTR, PTR_DTYPE)
-    bucket_keys = bucket_keys.at[flat].set(k2, mode="drop")
-    bucket_ptrs = bucket_ptrs.at[flat].set(r2.astype(PTR_DTYPE), mode="drop")
-    return (bucket_keys.reshape(num_buckets, slots),
-            bucket_ptrs.reshape(num_buckets, slots),
-            prev_rows, prev_vals, overflow)
+    # 2-D scatter (row num_buckets = out of range = drop): TPU keeps the
+    # planes slot-major, and a flat build + reshape would relayout them
+    # through 128-lane tiles at 16x their size.
+    at = (jnp.where(ok, b2, jnp.int32(num_buckets)),
+          jnp.minimum(rank, slots - 1))
+    bucket_keys = jnp.full((num_buckets, slots), EMPTY_KEY, jnp.int64)
+    bucket_ptrs = jnp.full((num_buckets, slots), NULL_PTR, PTR_DTYPE)
+    bucket_keys = bucket_keys.at[at].set(k2, mode="drop")
+    bucket_ptrs = bucket_ptrs.at[at].set(r2.astype(PTR_DTYPE), mode="drop")
+    return bucket_keys, bucket_ptrs, prev_rows, prev_vals, overflow
 
 
 def arena_insert_plan(bucket_keys, head_keys, is_head):
@@ -152,13 +154,13 @@ def arena_insert_plan(bucket_keys, head_keys, is_head):
              & (head_keys != EMPTY_KEY)[:, None])
     exists = match.any(axis=1)
     slot_exist = jnp.argmax(match, axis=1).astype(jnp.int32)
-    occ = jnp.sum(bucket_keys != EMPTY_KEY, axis=1).astype(jnp.int32)
+    occ = jnp.sum(row_keys != EMPTY_KEY, axis=1).astype(jnp.int32)  # b's
     new_head = is_head & ~exists
     b_or_inf = jnp.where(new_head, b, jnp.int32(nb))
     order = jnp.argsort(b_or_inf, stable=True)
     rank = (jnp.zeros(b.shape, jnp.int32)
             .at[order].set(_segment_rank(b_or_inf[order])))
-    slot_new = occ[b] + rank
+    slot_new = occ + rank
     overflow = jnp.sum(new_head & (slot_new >= slots))
     slot = jnp.where(exists, slot_exist, slot_new)
     ok = is_head & (slot < slots)

@@ -772,15 +772,14 @@ def _ingest_arrays(state, parent_blocks, cols_p, valid_p, *, schema, layout,
     flat_slot, overflow = hix.arena_insert_plan(state["bk"], hk, is_head)
     head_ptr = jnp.where(v_s, gid_s, NULL_PTR)
     hhi, hlo = hashing.split64(hk)
-    out["bk"] = (state["bk"].reshape(-1)
-                 .at[flat_slot].set(hk, mode="drop").reshape(nb_t, slots))
-    out["bhi"] = (state["bhi"].reshape(-1)
-                  .at[flat_slot].set(hhi, mode="drop").reshape(nb_t, slots))
-    out["blo"] = (state["blo"].reshape(-1)
-                  .at[flat_slot].set(hlo, mode="drop").reshape(nb_t, slots))
-    out["bptr"] = (state["bptr"].reshape(-1)
-                   .at[flat_slot].set(head_ptr, mode="drop")
-                   .reshape(nb_t, slots))
+    # 2-D scatters, never flatten: TPU stores an [nb, slots] plane
+    # slot-major, and a row-major reshape would relayout it into
+    # 128-lane tiles (16x its size) on every append.
+    at = (flat_slot // slots, flat_slot % slots)   # nb*slots -> row nb: drop
+    out["bk"] = state["bk"].at[at].set(hk, mode="drop")
+    out["bhi"] = state["bhi"].at[at].set(hhi, mode="drop")
+    out["blo"] = state["blo"].at[at].set(hlo, mode="drop")
+    out["bptr"] = state["bptr"].at[at].set(head_ptr, mode="drop")
 
     # -- hot-key tracker (skew detection, DESIGN.md §15) --------------------
     # Rides the same lexsort scaffold the chain writer just built — zero

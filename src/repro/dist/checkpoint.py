@@ -142,14 +142,17 @@ def save_dtable(path: str, dt: _dtable.DistributedTable):
         "table_version": int(np.asarray(dt.table.version).ravel()[0])})
 
 
-def restore_dtable(path: str,
-                   like: _dtable.DistributedTable) -> _dtable.DistributedTable:
-    """Restore a checkpoint into ``like``'s structure.
+def restore_dtable(path: str, like: _dtable.DistributedTable,
+                   rt: "_mesh.Runtime | None" = None
+                   ) -> _dtable.DistributedTable:
+    """Restore a checkpoint into ``like``'s structure, placed for ``rt``.
 
     ``like`` supplies the treedef (a dtable of the same construction —
     typically the live one or a freshly built empty clone).  Every leaf is
     validated against the template's shape; any mismatch (different shard
-    count, capacity, segment count...) raises ``ValueError``.
+    count, capacity, segment count...) raises ``ValueError``.  On a
+    shard_map runtime each restored shard lands on its own device
+    (``mesh.place``).
     """
     meta = _read_meta(path)
     if meta.get("num_shards", like.num_shards) != like.num_shards:
@@ -157,7 +160,7 @@ def restore_dtable(path: str,
             f"checkpoint was saved with {meta['num_shards']} shards; "
             f"template has {like.num_shards} — reshard_dtable the restored "
             f"table instead of restoring into a different topology")
-    return _restore_leaves(path, like, meta)
+    return _mesh.place(_restore_leaves(path, like, meta), rt)
 
 
 def save_table(path: str, t):

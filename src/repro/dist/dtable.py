@@ -117,7 +117,8 @@ class DistributedTable:
 
 def _route_host(cols, schema: Schema, num_shards: int, rows_per_batch: int,
                 valid=None):
-    """Partition columns by key hash into [num_shards, cap] padded arrays.
+    """Partition columns by key hash into [num_shards, cap] padded host
+    (numpy) arrays.
 
     The ingest path routes on the host (numpy) so it is exact — capacity is
     *derived* from the worst shard's row count, not guessed; query-time
@@ -141,8 +142,7 @@ def _route_host(cols, schema: Schema, num_shards: int, rows_per_batch: int,
         for c in schema.columns:
             out[c.name][d, :k] = np.asarray(cols[c.name])[m]
         vout[d, :k] = True
-    return ({name: jnp.asarray(a) for name, a in out.items()},
-            jnp.asarray(vout), cap)
+    return out, vout, cap
 
 
 def _build_stacked_segment(shard_cols, shard_valid, heads, schema: Schema, *,
@@ -193,11 +193,13 @@ def create_distributed(cols: dict, schema: Schema, num_shards: int, *,
     reserved = (capacity_class(cap, rows_per_batch) if reserve is None
                 else pad_to_batches(max(cap, int(reserve), 1),
                                     rows_per_batch))
-    pad = reserved - cap
-    if pad:
-        sc = {k: jnp.pad(v, ((0, 0), (0, pad))) for k, v in sc.items()}
-        sv = jnp.pad(sv, ((0, 0), (0, pad)))
-    heads = jnp.full((num_shards, reserved), NULL_PTR, PTR_DTYPE)
+    pad = ((0, 0), (0, reserved - cap))
+    heads = np.full((num_shards, reserved), NULL_PTR, PTR_DTYPE)
+    # padded on the host, then each shard's rows straight to its device
+    # (a stacked staging copy would hold every shard on device 0)
+    sc, sv, heads = jax.tree.map(jnp.asarray, mesh.place(
+        ({k: np.pad(v, pad) for k, v in sc.items()}, np.pad(sv, pad), heads),
+        rt))
     seg = _build_stacked_segment(sc, sv, heads, schema, row_base=0,
                                  rows_per_batch=rows_per_batch,
                                  layout=layout, slots=slots, rt=rt)
@@ -212,8 +214,9 @@ def create_distributed(cols: dict, schema: Schema, num_shards: int, *,
                          rows_per_batch=rows_per_batch, layout=layout,
                          version=jnp.zeros((num_shards,), jnp.int32),
                          slots=slots, hot=hot)
-    return DistributedTable(table=table, num_shards=num_shards,
-                            version=jnp.asarray(0, jnp.int32))
+    return mesh.place(DistributedTable(table=table, num_shards=num_shards,
+                                       version=jnp.asarray(0, jnp.int32)),
+                      rt)
 
 
 @functools.lru_cache(maxsize=None)
@@ -269,6 +272,7 @@ def append_distributed(dt: DistributedTable, cols: dict, valid=None,
     rt = mesh.resolve(rt).check(dt.num_shards)
     schema, rpb = dt.schema, dt.rows_per_batch
     sc, sv, cap = _route_host(cols, schema, dt.num_shards, rpb, valid)
+    sc, sv = jax.tree.map(jnp.asarray, (sc, sv))
     # per-shard fit: routed rows are left-packed, so counts are sv sums
     # host syncs via jax.device_get: the benchmarks' SyncCounter funnel
     counts = np.asarray(jax.device_get(sv)).sum(axis=1)
@@ -381,6 +385,7 @@ def enqueue_distributed(dt: DistributedTable, queue, cols: dict, valid=None,
     n = int(np.shape(cols[dt.schema.key])[0])
     nv = n if valid is None else int(np.asarray(valid, bool).sum())
     sc, sv, cap = _route_host(cols, dt.schema, dt.num_shards, 1, valid)
+    sc, sv = jax.tree.map(jnp.asarray, (sc, sv))
     if cap > queue.lane_rows:
         raise table_mod.QueueOverflow(
             f"one shard owns {cap} delta rows but queue lanes hold "

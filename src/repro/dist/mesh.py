@@ -46,21 +46,9 @@ import dataclasses
 
 import jax
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 AXIS = "shards"
-
-
-def _shard_map_impl(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (newer: ``check_vma``;
-    older: the experimental API with ``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,6 +101,31 @@ def mesh_runtime(num_shards: int, *, devices=None,
     return Runtime(backend="shard_map", mesh=mesh, axis=axis)
 
 
+def place(tree, rt: Runtime | None):
+    """Put a shard-stacked pytree where the runtime keeps it — THE
+    placement seam for everything built or rebuilt outside ``axis_map``
+    (a fresh dtable, an empty append ring, a restored or spliced shard).
+
+    shard_map backend: a leaf with the leading ``[num_shards]`` axis gets
+    ``NamedSharding(mesh, P(axis))`` (shard ``i``'s slice on device
+    ``i``); any other leaf (a global scalar) is replicated over the mesh.
+    These are the shardings the axis-mapped jits hand back, so a jitted
+    read site sees the same input shardings before and after a write and
+    never retraces on placement.  vmap backend: unchanged.
+    """
+    rt = resolve(rt)
+    if rt.backend != "shard_map":
+        return tree
+    split = NamedSharding(rt.mesh, P(rt.axis))
+    whole = NamedSharding(rt.mesh, P())
+
+    def put(x):
+        stacked = np.ndim(x) >= 1 and np.shape(x)[0] == rt.num_devices
+        return jax.device_put(x, split if stacked else whole)
+
+    return jax.tree.map(put, tree)
+
+
 def resolve(rt: Runtime | None) -> Runtime:
     """None -> the default vmap backend (back-compat for every call site)."""
     return rt if rt is not None else vmap_runtime()
@@ -154,6 +167,7 @@ def axis_map(fn, rt: Runtime | None, in_axes=0):
             out = fn(*local)
             return jax.tree.map(lambda x: x[None], out)
 
-        return _shard_map_impl(blocked, rt.mesh, in_specs, P(rt.axis))(*args)
+        return jax.shard_map(blocked, mesh=rt.mesh, in_specs=in_specs,
+                             out_specs=P(rt.axis), check_vma=False)(*args)
 
     return mapped

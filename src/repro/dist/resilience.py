@@ -53,6 +53,7 @@ from repro.core import table as table_mod
 from repro.core.hashindex import EMPTY_KEY
 from repro.dist import checkpoint as _ckpt
 from repro.dist import dtable as _dtable
+from repro.dist import mesh as _mesh
 from repro.dist import runtime as _runtime
 
 FAULT_KINDS = ("shard_loss", "straggler", "capacity_pressure",
@@ -331,7 +332,7 @@ class RecoveryManager:
         if fresh is None:                  # budget exhausted: degrade
             self.dead.add(shard)
             return False
-        healed = _runtime.splice_shard(dt, shard, fresh)
+        healed = _runtime.splice_shard(dt, shard, fresh, rt=self.frame.rt)
         if healed.replica is not None:
             # fail_shard marked the mirror stale (the dead executor's
             # copy died with it); with tracker and rows spliced back
@@ -383,7 +384,8 @@ class RecoveryManager:
             if f.kind == "shard_loss":
                 self.frame = dataclasses.replace(
                     self.frame,
-                    data=_runtime.fail_shard(self.frame.data, f.shard),
+                    data=_runtime.fail_shard(self.frame.data, f.shard,
+                                             rt=self.frame.rt),
                     queue=self._fail_queue_shard(f.shard))
             elif f.kind == "capacity_pressure":
                 self._pressure_divisor = max(2.0, float(f.severity))
@@ -409,12 +411,12 @@ class RecoveryManager:
         q = self.frame.queue
         if q is None:
             return None
-        blanked = dataclasses.replace(
+        blanked = _mesh.place(dataclasses.replace(
             q,
             cols={k: v.at[shard].set(0) for k, v in q.cols.items()},
             valid=q.valid.at[shard].set(False),
             fills=q.fills.at[shard].set(0),
-            count=q.count.at[shard].set(0))
+            count=q.count.at[shard].set(0)), self.frame.rt)
         return table_mod._set_queue_mirror(blanked,
                                            *table_mod.queue_pending(q))
 

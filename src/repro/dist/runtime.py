@@ -120,7 +120,7 @@ class Lineage:
         dist/checkpoint.py) or a version outside the log."""
         from repro.dist import checkpoint as _ckpt
         suffix = self.deltas_since(version)   # validate before touching IO
-        dt = _ckpt.restore_dtable(checkpoint_path, like)
+        dt = _ckpt.restore_dtable(checkpoint_path, like, rt=rt)
         return self._apply(dt, suffix, rt)
 
     def replay(self, num_shards: int,
@@ -157,14 +157,15 @@ class Lineage:
                       else (_dtable.table_mod.SKETCH_DEPTH,
                             _dtable.table_mod.SKETCH_WIDTH))
             dt = dataclasses.replace(dt, table=dataclasses.replace(
-                dt.table, hot=_dtable.table_mod.empty_tracker(
+                dt.table, hot=_mesh.place(_dtable.table_mod.empty_tracker(
                     h.keys.shape[-1], mode=h.mode, sketch_depth=sd,
-                    sketch_width=sw, num_shards=num_shards)))
+                    sketch_width=sw, num_shards=num_shards), rt)))
         return self._apply(dt, self.deltas, rt)
 
 
-def fail_shard(dt: _dtable.DistributedTable,
-               shard: int) -> _dtable.DistributedTable:
+def fail_shard(dt: _dtable.DistributedTable, shard: int,
+               rt: "_mesh.Runtime | None" = None
+               ) -> _dtable.DistributedTable:
     """Simulate executor loss: blank the shard's slice of every leaf.
 
     Shapes (and therefore jit caches) are preserved — only the shard's
@@ -172,7 +173,7 @@ def fail_shard(dt: _dtable.DistributedTable,
     structures are blanked to their *sentinels* (EMPTY keys, NULL
     pointers, valid=False), not zero: zero is a legal key and a legal row
     id, and a dead shard must answer every lookup with a miss, never a
-    fabricated key-0 match.
+    fabricated key-0 match.  The blanked dtable stays placed for ``rt``.
     """
 
     def kill(leaf, fill):
@@ -222,17 +223,18 @@ def fail_shard(dt: _dtable.DistributedTable,
         # bit-identically first.
         out = dataclasses.replace(out, replica=dataclasses.replace(
             dt.replica, version=jnp.asarray(-1, jnp.int32)))
-    return out
+    return _mesh.place(out, rt)
 
 
 def splice_shard(dt: _dtable.DistributedTable, shard: int,
-                 fresh: _dtable.DistributedTable
+                 fresh: _dtable.DistributedTable,
+                 rt: "_mesh.Runtime | None" = None
                  ) -> _dtable.DistributedTable:
     """Splice one shard's slice of ``fresh`` into ``dt`` (the recovery
-    state machine's final step — DESIGN.md §12).  Leaf shapes are
-    untouched, so the spliced dtable re-enters every live jit cache
-    entry.  Raises if the two dtables disagree on the global version
-    (a lineage that missed a ``record_append``)."""
+    state machine's final step — DESIGN.md §12).  Leaf shapes and, for
+    ``rt``, placements are untouched, so the spliced dtable re-enters
+    every live jit cache entry.  Raises if the two dtables disagree on
+    the global version (a lineage that missed a ``record_append``)."""
     if int(np.asarray(fresh.version)) != int(np.asarray(dt.version)):
         raise ValueError(
             f"lineage replays to version {int(np.asarray(fresh.version))} "
@@ -244,7 +246,7 @@ def splice_shard(dt: _dtable.DistributedTable, shard: int,
         return broken.at[shard].set(rebuilt[shard])
 
     table = jax.tree.map(splice, dt.table, fresh.table)
-    return dataclasses.replace(dt, table=table)
+    return _mesh.place(dataclasses.replace(dt, table=table), rt)
 
 
 def rebuild_shard(dt: _dtable.DistributedTable, shard: int,
@@ -261,7 +263,7 @@ def rebuild_shard(dt: _dtable.DistributedTable, shard: int,
     ``record_append``).
     """
     fresh = lineage.replay(dt.num_shards, rt=rt, like=dt)
-    return splice_shard(dt, shard, fresh)
+    return splice_shard(dt, shard, fresh, rt=rt)
 
 
 @dataclasses.dataclass

@@ -533,9 +533,12 @@ class IndexedFrame:
         q = self.queue
         if q is not None and (q.lanes, q.lane_rows) == (lanes, lr):
             return self
-        q = table_mod.empty_queue(
-            self.schema, lanes=lanes, lane_rows=lr,
-            num_shards=self.num_shards if self.is_distributed else None)
+        if not self.is_distributed:
+            q = table_mod.empty_queue(self.schema, lanes=lanes, lane_rows=lr)
+        else:
+            q = self._placed(table_mod.empty_queue(
+                self.schema, lanes=lanes, lane_rows=lr,
+                num_shards=self.num_shards))
         return dataclasses.replace(self, queue=q)
 
     def enqueue(self, cols, valid=None, *, donate: bool = True,
@@ -644,8 +647,8 @@ class IndexedFrame:
                              "partition_by=...)) to track every partition")
         k = table_mod.DEFAULT_HOT_TOP_K if top_k is None else int(top_k)
         if self.is_distributed:
-            hot = table_mod.empty_tracker(k, mode=mode,
-                                          num_shards=self.num_shards)
+            hot = self._placed(table_mod.empty_tracker(
+                k, mode=mode, num_shards=self.num_shards))
             data = dataclasses.replace(
                 self.data, table=dataclasses.replace(self.data.table,
                                                      hot=hot))
@@ -669,8 +672,8 @@ class IndexedFrame:
             kw["capacity"] = int(capacity)
         if max_matches is not None:
             kw["max_matches"] = int(max_matches)
-        return dataclasses.replace(self, data=dd.attach_replica(self.data,
-                                                                **kw))
+        return dataclasses.replace(
+            self, data=self._placed(dd.attach_replica(self.data, **kw)))
 
     def refresh_replica(self) -> "IndexedFrame":
         """Re-mirror the current global top-H hot keys at the live
@@ -679,6 +682,13 @@ class IndexedFrame:
         when a mirror is attached."""
         return dataclasses.replace(
             self, data=_dtable().refresh_replica(self.data, rt=self.rt))
+
+    def _placed(self, tree):
+        """A shard-stacked pytree put on this frame's mesh, leaf by leaf
+        (``dist.mesh.place``) — for state built outside an axis-mapped
+        jit, so the read sites never see a new placement."""
+        from repro.dist import mesh
+        return mesh.place(tree, self.rt)
 
     def _refreshed(self, data):
         """Auto re-mirror after a version bump: a stale mirror is always
@@ -776,9 +786,10 @@ class IndexedFrame:
         supplies the treedef AND the runtime, exactly as
         ``dist.checkpoint.restore_dtable``)."""
         if like.is_partitioned:
-            data = partition_mod.restore_partitioned(path, like.data)
+            data = partition_mod.restore_partitioned(path, like.data,
+                                                    rt=like.rt)
         elif like.is_distributed:
-            data = _checkpoint().restore_dtable(path, like.data)
+            data = _checkpoint().restore_dtable(path, like.data, rt=like.rt)
         else:
             data = _checkpoint().restore_table(path, like.data)
         return dataclasses.replace(like, data=data)

@@ -2,8 +2,9 @@
 
 ``probe`` is a drop-in accelerated replacement for
 ``repro.core.hashindex.probe`` — same signature, same results (tests sweep
-both).  ``fused_lookup`` is the multi-segment hot path: probe + in-kernel
-chain walk over a table's stored Snapshot (DESIGN.md §3).  The wrappers own
+both) — run as the one-segment case of the fused kernel.
+``fused_lookup`` is the multi-segment hot path: probe + in-kernel chain
+walk over a table's stored Snapshot (DESIGN.md §3).  The wrappers own
 everything that does not belong in the vector kernel: bucket-id hashing
 (64-bit scalar math), int64 -> (hi, lo) plane splitting, tile padding, and
 EMPTY-key masking.
@@ -29,27 +30,22 @@ from repro.core.pointers import NULL_PTR
 from repro.kernels import hash_probe, ref, runtime
 from repro.kernels import decode_attention as _da
 
-_split64 = hashing.split64  # kept under the old name for external callers
-
 
 def probe(index: HashIndex, query_keys, *, interpret: bool | None = None):
-    """Latest row id per query key — Pallas-accelerated probe."""
-    q = jnp.asarray(query_keys, jnp.int64)
-    nq = q.shape[0]
-    tile = hash_probe.QUERY_TILE
-    pad = (-nq) % tile
-    qp = jnp.pad(q, (0, pad), constant_values=int(EMPTY_KEY))
-
-    bids = hashing.bucket_hash(qp, index.num_buckets)
-    qhi, qlo = hashing.split64(qp)
-    khi, klo = hashing.split64(index.bucket_keys)
-
-    out = hash_probe.probe_tiles(bids, qhi, qlo, khi, klo,
-                                 index.bucket_ptrs, interpret=interpret)
-    out = out[:nq]
-    # EMPTY query keys can never match (EMPTY slots hold NULL ptrs), but be
-    # explicit for defense in depth:
-    return jnp.where(q == EMPTY_KEY, NULL_PTR, out)
+    """Latest row id per query key — the fused kernel's one-segment,
+    ``max_matches=1`` case.  A bare index has no chains and no arena, so
+    ``prev`` is one all-NULL lane row (every hop ends) and ``fill`` masks
+    nothing."""
+    hi, lo = hashing.split64(index.bucket_keys)
+    block = snapshot.FlatBlock(key_hi=hi, key_lo=lo, ptrs=index.bucket_ptrs,
+                               num_buckets=index.num_buckets)
+    snap = snapshot.probe_view(
+        (block,), jnp.full((hash_probe.LANES,), NULL_PTR, jnp.int32),
+        jnp.int32(jnp.iinfo(jnp.int32).max),
+        bucket_counts=(index.num_buckets,), layout="row")
+    rows, _ = fused_lookup(query_keys, snap, max_matches=1,
+                           interpret=interpret, use_kernel=True)
+    return rows[:, 0]
 
 
 # ---------------------------------------------------------------------------

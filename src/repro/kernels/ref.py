@@ -12,15 +12,6 @@ import jax.numpy as jnp
 NULL = jnp.int32(-1)
 
 
-def probe_ref(bucket_ids, q_hi, q_lo, keys_hi, keys_lo, ptrs):
-    """Oracle for hash_probe.probe_tiles: one [Q, S] gather + compare."""
-    row_hi = keys_hi[bucket_ids]           # [Q, S]
-    row_lo = keys_lo[bucket_ids]
-    row_ptr = ptrs[bucket_ids]
-    match = (row_hi == q_hi[:, None]) & (row_lo == q_lo[:, None])
-    return jnp.max(jnp.where(match, row_ptr, NULL), axis=1)
-
-
 def fused_probe_ref(bucket_ids, q_hi, q_lo, snapshot):
     """Oracle for the probe stage of hash_probe.fused_lookup_tiles.
 

@@ -18,13 +18,9 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types``/``AxisType``
-    only exist on newer jax; older releases are Auto-by-default anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD-propagated)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(shape))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -221,18 +221,6 @@ def moe_ffn_sorted(p, x, cfg: ModelConfig):
     return out, aux
 
 
-def _shard_map_compat(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions (older jax: experimental API
-    with ``check_rep`` instead of ``check_vma``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-
-
 def moe_ffn_ep(p, x, cfg: ModelConfig, *, mesh, dp, model_axis: str):
     """Expert-parallel sorted dispatch under shard_map.
 
@@ -270,11 +258,11 @@ def moe_ffn_ep(p, x, cfg: ModelConfig, *, mesh, dp, model_axis: str):
     xspec = P(dp, None, None)
     espec = P(model_axis, None, None)
     bias = p.get("router_bias")
-    out, aux = _shard_map_compat(
-        local_fn, mesh,
-        (xspec, P(None, None), None if bias is None else P(None),
-         espec, espec, espec),
-        (xspec, P()),
+    out, aux = jax.shard_map(
+        local_fn, mesh=mesh,
+        in_specs=(xspec, P(None, None), None if bias is None else P(None),
+                  espec, espec, espec),
+        out_specs=(xspec, P()), check_vma=False,
     )(x, p["router"], bias, p["w_gate"], p["w_up"], p["w_down"])
 
     if m.num_shared:

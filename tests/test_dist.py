@@ -199,3 +199,25 @@ def test_restore_shape_mismatch_raises(tmp_path, dt_and_cols):
     bigger = checkpoint.reshard_dtable(dt, 8)
     with pytest.raises(ValueError):
         checkpoint.restore_dtable(path, bigger)
+
+
+def test_mesh_place_puts_each_shard_on_its_device(rng):
+    """``mesh.place``: stacked leaves split one shard per device, global
+    leaves replicated, on every device of the mesh; vmap: unchanged."""
+    import jax
+    from repro.dist import mesh
+    n = len(jax.devices())
+    rt = mesh.mesh_runtime(n)
+    cols = {"k": rng.integers(0, 500, 300).astype(np.int64),
+            "v": rng.random(300).astype(np.float32)}
+    dt = create_distributed(cols, Schema.of("k", k="int64", v="float32"),
+                            n, rows_per_batch=64, rt=rt)
+    for leaf in jax.tree_util.tree_leaves(dt):
+        assert leaf.sharding.device_set == set(jax.devices())
+        if leaf.ndim:
+            assert leaf.shape[0] == n
+            assert all(s.data.shape[0] == 1 for s in leaf.addressable_shards)
+        else:
+            assert leaf.sharding.is_fully_replicated
+    tree = {"stacked": jnp.zeros((n, 3)), "scalar": jnp.zeros(())}
+    assert mesh.place(tree, mesh.vmap_runtime()) is tree

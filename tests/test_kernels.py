@@ -131,3 +131,45 @@ def test_decode_attention_single_token(rng):
     out = decode_paged(q, kp, vp, pt, lengths, d ** -0.5, interpret=True)
     np.testing.assert_allclose(np.asarray(out)[0, 0],
                                np.asarray(vp)[0, 0, 0], rtol=1e-5)
+
+
+# --- the kernel under vmap (the dist layer's one-device runtime) ------------
+
+def _stackable_tables(rng, n_tables):
+    from repro.core import Schema, append, create_index
+    sch = Schema.of("k", k="int64", v="float32")
+    tables = []
+    for _ in range(n_tables):
+        t = create_index({"k": rng.integers(0, 90, 300).astype(np.int64),
+                          "v": rng.random(300).astype(np.float32)},
+                         sch, rows_per_batch=64, reserve=512)
+        t = append(t, {"k": rng.integers(0, 90, 40).astype(np.int64),
+                       "v": rng.random(40).astype(np.float32)})
+        tables.append(t)
+    return tables
+
+
+@pytest.mark.parametrize("stacked", [True, False])
+def test_fused_lookup_kernel_under_vmap(rng, stacked):
+    """vmap over stacked snapshots (one table per lane) and over query
+    sets against one table both match the per-table oracle."""
+    tables = _stackable_tables(rng, 3)
+    snaps = [t.snapshot for t in tables]
+    q = rng.integers(-5, 100, (3, 70)).astype(np.int64)
+
+    def look(keys, snap):
+        return ops.fused_lookup(keys, snap, max_matches=6, use_kernel=True,
+                                interpret=True)
+
+    if stacked:
+        stack = jax.tree.map(lambda *xs: jnp.stack(xs), *snaps)
+        rows, trunc = jax.vmap(look)(jnp.asarray(q), stack)
+    else:
+        rows, trunc = jax.vmap(look, in_axes=(0, None))(jnp.asarray(q),
+                                                        snaps[0])
+        snaps = [snaps[0]] * 3
+    for i, snap in enumerate(snaps):
+        ro, to = ops.fused_lookup(q[i], snap, max_matches=6,
+                                  use_kernel=False)
+        np.testing.assert_array_equal(np.asarray(rows[i]), np.asarray(ro))
+        np.testing.assert_array_equal(np.asarray(trunc[i]), np.asarray(to))

@@ -613,6 +613,9 @@ for step in range(6):
     twin = twin.append(delta)
 assert mgr.stats.recoveries == 1 and not mgr.dead, vars(mgr.stats)
 assert mgr.retraces == 1, mgr.retraces
+# the healed frame is placed like the built one: every leaf spans the mesh
+for leaf in jax.tree_util.tree_leaves(mgr.frame):
+    assert leaf.sharding.device_set == set(jax.devices()), leaf.sharding
 print("MESH_FAILURE_8DEV_OK")
 """
 
@@ -623,6 +626,7 @@ def _run_forced_8(script: str) -> subprocess.CompletedProcess:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never a chip
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, env=env,

@@ -382,6 +382,9 @@ eng.drain()
 assert replay_unbatched(frame0, reqs, eng.write_log) == 0
 assert eng.zero_retraces_after_warmup, (eng.retraces, eng.expected_traces)
 assert eng.verify_version()
+# every leaf of the flushed frame, its ring included, spans the mesh
+for leaf in jax.tree_util.tree_leaves(eng.frame):
+    assert leaf.sharding.device_set == set(jax.devices()), leaf.sharding
 print("SERVE_8DEV_OK")
 """
 
@@ -402,6 +405,7 @@ def test_serve_shard_map_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never a chip
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", _SUBPROCESS_SERVE],
                           capture_output=True, text=True, env=env,

@@ -361,6 +361,7 @@ def test_queue_parity_shard_map_subprocess():
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never a chip
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.run([sys.executable, "-c", _SUBPROCESS_QUEUE],
                           capture_output=True, text=True, env=env,

@@ -424,6 +424,7 @@ def _run_forced_8(script: str) -> subprocess.CompletedProcess:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices, never a chip
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     return subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, env=env,
