@@ -338,11 +338,8 @@ def run_four_chips(args, jax, clock):
     sch = Schema.of("k", k="int64", v="float32")
 
     t0, c0 = time.perf_counter(), clock.seconds
-    # An explicit arena reserve: hash routing leaves the fullest shard a
-    # little over --rows, which would double the default capacity class.
     frame = IndexedFrame.from_columns({"k": keys, "v": vals}, sch,
-                                      num_shards=shards, rt=rt,
-                                      reserve=2 * args.rows)
+                                      num_shards=shards, rt=rt)
     frame = frame.with_queue()
     jax.block_until_ready(frame)
     placement = {"build": spread_over(frame, devices)}

@@ -767,10 +767,10 @@ def save_partitioned(path: str, pt: PartitionedTable):
             _ckpt().save_table(sub, part)
 
 
-def restore_partitioned(path: str, like: PartitionedTable,
-                        rt=None) -> PartitionedTable:
-    """Restore into ``like``'s structure (``like`` supplies treedefs;
-    distributed partitions are placed for ``rt``)."""
+def restore_partitioned(path: str, like: PartitionedTable
+                        ) -> PartitionedTable:
+    """Restore into ``like``'s structure (``like`` supplies treedefs and
+    the runtime, per-partition)."""
     with open(os.path.join(path, "partitions.json")) as f:
         meta = json.load(f)
     spec = spec_from_dict(meta["spec"])
@@ -780,7 +780,7 @@ def restore_partitioned(path: str, like: PartitionedTable,
     for i, part in enumerate(like.parts):
         sub = os.path.join(path, f"part_{spec.ids[i]}")
         if like.dist:
-            parts.append(_ckpt().restore_dtable(sub, part, rt=rt))
+            parts.append(_ckpt().restore_dtable(sub, part))
         else:
             parts.append(_ckpt().restore_table(sub, part))
     return dataclasses.replace(

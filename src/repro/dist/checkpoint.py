@@ -142,17 +142,16 @@ def save_dtable(path: str, dt: _dtable.DistributedTable):
         "table_version": int(np.asarray(dt.table.version).ravel()[0])})
 
 
-def restore_dtable(path: str, like: _dtable.DistributedTable,
-                   rt: "_mesh.Runtime | None" = None
-                   ) -> _dtable.DistributedTable:
-    """Restore a checkpoint into ``like``'s structure, placed for ``rt``.
+def restore_dtable(path: str,
+                   like: _dtable.DistributedTable) -> _dtable.DistributedTable:
+    """Restore a checkpoint into ``like``'s structure and placement.
 
     ``like`` supplies the treedef (a dtable of the same construction —
     typically the live one or a freshly built empty clone).  Every leaf is
     validated against the template's shape; any mismatch (different shard
-    count, capacity, segment count...) raises ``ValueError``.  On a
-    shard_map runtime each restored shard lands on its own device
-    (``mesh.place``).
+    count, capacity, segment count...) raises ``ValueError``.  Each
+    restored leaf lands on ``like``'s sharding (``mesh.place_like``): on
+    a shard_map mesh every shard on its own device.
     """
     meta = _read_meta(path)
     if meta.get("num_shards", like.num_shards) != like.num_shards:
@@ -160,7 +159,7 @@ def restore_dtable(path: str, like: _dtable.DistributedTable,
             f"checkpoint was saved with {meta['num_shards']} shards; "
             f"template has {like.num_shards} — reshard_dtable the restored "
             f"table instead of restoring into a different topology")
-    return _mesh.place(_restore_leaves(path, like, meta), rt)
+    return _mesh.place_like(_restore_leaves(path, like, meta), like)
 
 
 def save_table(path: str, t):
@@ -198,7 +197,8 @@ def reshard_dtable(dt: _dtable.DistributedTable, num_shards: int, *,
     fresh = _dtable.create_distributed(
         cols, dt.schema, num_shards, rows_per_batch=dt.rows_per_batch,
         layout=dt.layout, slots=dt.slots, rt=rt_out)
-    return dataclasses.replace(fresh, version=dt.version)
+    return dataclasses.replace(
+        fresh, version=_mesh.place(dt.version, rt_out, stacked=False))
 
 
 # Row collection lives with the dtable now (compact_distributed shares it);

@@ -145,6 +145,28 @@ def _route_host(cols, schema: Schema, num_shards: int, rows_per_batch: int,
     return out, vout, cap
 
 
+@functools.lru_cache(maxsize=None)
+def _segment_build_fn(rt: mesh.Runtime, schema: Schema, row_base: int,
+                      rows_per_batch: int, layout: str, num_buckets: int,
+                      slots: int):
+    """Jitted, axis-mapped segment build for one runtime and static
+    configuration: one program per shape instead of an op-by-op dispatch
+    of the build's sorts and scatters over the shard axis."""
+    return jax.jit(mesh.axis_map(
+        lambda c, v, h: make_segment_arrays(
+            c, v, h, schema, row_base=row_base,
+            rows_per_batch=rows_per_batch, layout=layout,
+            num_buckets=num_buckets, slots=slots), rt))
+
+
+@functools.lru_cache(maxsize=None)
+def _snapshot_fn(rt: mesh.Runtime, schema: Schema, layout: str):
+    """Jitted, axis-mapped flat-data snapshot of a fresh one-segment
+    dtable (see ``create_distributed``)."""
+    return jax.jit(mesh.axis_map(lambda s: snap_mod.snapshot_from_segments(
+        (s,), layout, schema=schema, with_data=True), rt))
+
+
 def _build_stacked_segment(shard_cols, shard_valid, heads, schema: Schema, *,
                            row_base: int, rows_per_batch: int, layout: str,
                            slots: int, rt: mesh.Runtime | None = None,
@@ -155,16 +177,32 @@ def _build_stacked_segment(shard_cols, shard_valid, heads, schema: Schema, *,
     cap = int(shard_valid.shape[1])
     nb = hix.suggest_num_buckets(cap, slots)
     for _ in range(max_retries):
-        seg, overflow = mesh.axis_map(
-            lambda c, v, h, _nb=nb: make_segment_arrays(
-                c, v, h, schema, row_base=row_base,
-                rows_per_batch=rows_per_batch, layout=layout,
-                num_buckets=_nb, slots=slots), rt)(shard_cols, shard_valid,
-                                                   heads)
+        seg, overflow = _segment_build_fn(
+            mesh.resolve(rt), schema, row_base, rows_per_batch, layout, nb,
+            slots)(shard_cols, shard_valid, heads)
         if int(jnp.max(overflow)) == 0:
             return seg
         nb *= 2
     raise RuntimeError("distributed segment build kept overflowing")
+
+
+def place_dtable(dt: "DistributedTable",
+                 rt: mesh.Runtime | None) -> "DistributedTable":
+    """``dt`` on ``rt``'s mesh by structure: the stacked table split one
+    shard per device, the global version and the replica replicated."""
+    return dataclasses.replace(
+        dt, table=mesh.place(dt.table, rt),
+        version=mesh.place(dt.version, rt, stacked=False),
+        replica=mesh.place(dt.replica, rt, stacked=False))
+
+
+def shard_capacity(share: int, fullest: int, rows_per_batch: int) -> int:
+    """Per-shard arena capacity of a new dtable: the capacity class of
+    the routed per-shard ``share`` of the rows, raised to the
+    power-of-two cover of the ``fullest`` shard when skew puts more than
+    that class on one shard."""
+    return max(capacity_class(share, rows_per_batch),
+               capacity_class(fullest, rows_per_batch, growth=1))
 
 
 def create_distributed(cols: dict, schema: Schema, num_shards: int, *,
@@ -182,17 +220,22 @@ def create_distributed(cols: dict, schema: Schema, num_shards: int, *,
     (probe planes, prev, row data) must live in the stored pytree.
 
     Every shard's planes are reserved to one common capacity class
-    (DESIGN.md §4) — derived from the worst shard's row count, or from
-    ``reserve`` (per-shard minimum rows; ``0`` = no over-allocation, the
-    pre-arena layout) — so appends within the class run the in-place
-    ingest with zero pytree shape change on every shard at once.
+    (DESIGN.md §4) so appends within the class run the in-place ingest
+    with zero pytree shape change on every shard at once.  The class is
+    that of the routed per-shard share (``shard_capacity``): hash routing
+    leaves the fullest shard a little over the share, and sizing from the
+    fullest shard would double every shard's planes whenever the share
+    sits at a class edge.  ``reserve`` overrides it (per-shard minimum
+    rows; ``0`` = no over-allocation, the pre-arena layout).
     """
     rt = mesh.resolve(rt).check(num_shards)
     sc, sv, cap = _route_host(cols, schema, num_shards, rows_per_batch,
                               valid)
-    reserved = (capacity_class(cap, rows_per_batch) if reserve is None
-                else pad_to_batches(max(cap, int(reserve), 1),
-                                    rows_per_batch))
+    if reserve is None:
+        reserved = shard_capacity(-(-int(sv.sum()) // num_shards), cap,
+                                  rows_per_batch)
+    else:
+        reserved = pad_to_batches(max(cap, int(reserve), 1), rows_per_batch)
     pad = ((0, 0), (0, reserved - cap))
     heads = np.full((num_shards, reserved), NULL_PTR, PTR_DTYPE)
     # padded on the host, then each shard's rows straight to its device
@@ -203,8 +246,7 @@ def create_distributed(cols: dict, schema: Schema, num_shards: int, *,
     seg = _build_stacked_segment(sc, sv, heads, schema, row_base=0,
                                  rows_per_batch=rows_per_batch,
                                  layout=layout, slots=slots, rt=rt)
-    snap = mesh.axis_map(lambda s: snap_mod.snapshot_from_segments(
-        (s,), layout, schema=schema, with_data=True), rt)(seg)
+    snap = _snapshot_fn(rt, schema, layout)(seg)
     # track_hot attaches an EMPTY per-shard tracker (created rows are not
     # back-counted — see table.with_hot: replay-deterministic)
     hot = (None if track_hot is None
@@ -214,9 +256,9 @@ def create_distributed(cols: dict, schema: Schema, num_shards: int, *,
                          rows_per_batch=rows_per_batch, layout=layout,
                          version=jnp.zeros((num_shards,), jnp.int32),
                          slots=slots, hot=hot)
-    return mesh.place(DistributedTable(table=table, num_shards=num_shards,
-                                       version=jnp.asarray(0, jnp.int32)),
-                      rt)
+    return place_dtable(DistributedTable(table=table, num_shards=num_shards,
+                                         version=jnp.asarray(0, jnp.int32)),
+                        rt)
 
 
 @functools.lru_cache(maxsize=None)
@@ -484,10 +526,10 @@ def compact_distributed(dt: DistributedTable, *,
     row count, so post-compaction appends re-enter the in-place path.
     """
     cols = collect_cols(dt, rt=rt)
+    rt_out = rt_out if rt_out is not None else rt
     fresh = create_distributed(
         cols, dt.schema, dt.num_shards, rows_per_batch=dt.rows_per_batch,
-        layout=dt.layout, slots=dt.slots, reserve=reserve,
-        rt=rt_out if rt_out is not None else rt)
+        layout=dt.layout, slots=dt.slots, reserve=reserve, rt=rt_out)
     old_tv = int(np.asarray(dt.table.version).ravel()[0])
     bump = 1 if _bump_version else 0
     # compaction rewrites storage, not history: tracker counts and the
@@ -495,8 +537,9 @@ def compact_distributed(dt: DistributedTable, *,
     table = dataclasses.replace(
         fresh.table, version=jnp.full((dt.num_shards,), old_tv + bump,
                                       jnp.int32), hot=dt.table.hot)
-    return DistributedTable(table=table, num_shards=dt.num_shards,
-                            version=dt.version + bump, replica=dt.replica)
+    return place_dtable(DistributedTable(
+        table=table, num_shards=dt.num_shards, version=dt.version + bump,
+        replica=dt.replica), rt_out)
 
 
 # ---------------------------------------------------------------------------
@@ -791,7 +834,9 @@ def refresh_replica(dt: DistributedTable, *,
                          "(attach_replica first)")
     if dt.table.hot is None:
         raise ValueError("refresh_replica: table has no hot-key tracker")
-    return dataclasses.replace(dt, replica=_refresh_fn(rt)(dt))
+    # the un-stacked mirror is replicated, as attached (place_dtable)
+    return dataclasses.replace(dt, replica=mesh.place(
+        _refresh_fn(rt)(dt), rt, stacked=False))
 
 
 def _replica_split(dt: DistributedTable, q):

@@ -101,29 +101,39 @@ def mesh_runtime(num_shards: int, *, devices=None,
     return Runtime(backend="shard_map", mesh=mesh, axis=axis)
 
 
-def place(tree, rt: Runtime | None):
-    """Put a shard-stacked pytree where the runtime keeps it — THE
-    placement seam for everything built or rebuilt outside ``axis_map``
-    (a fresh dtable, an empty append ring, a restored or spliced shard).
+def place(tree, rt: Runtime | None, *, stacked: bool = True):
+    """Put a pytree where the runtime keeps it — THE placement seam for
+    everything built outside ``axis_map`` (a fresh dtable, an empty append
+    ring, a tracker, a hot-key replica).
 
-    shard_map backend: a leaf with the leading ``[num_shards]`` axis gets
-    ``NamedSharding(mesh, P(axis))`` (shard ``i``'s slice on device
-    ``i``); any other leaf (a global scalar) is replicated over the mesh.
-    These are the shardings the axis-mapped jits hand back, so a jitted
-    read site sees the same input shardings before and after a write and
-    never retraces on placement.  vmap backend: unchanged.
+    shard_map backend: a ``stacked`` tree (every leaf carries the leading
+    ``[num_shards]`` axis — a table, a ring, a tracker) is split with
+    ``NamedSharding(mesh, P(axis))``, shard ``i``'s slice on device ``i``;
+    any other tree (the dtable's global version, the replica) is
+    replicated over the mesh.  Placement follows the caller's structure,
+    never a leaf's shape.  These are the shardings the axis-mapped jits
+    hand back, so a jitted read site sees the same input shardings before
+    and after a write and never retraces on placement.  vmap backend:
+    unchanged.
     """
     rt = resolve(rt)
     if rt.backend != "shard_map":
         return tree
-    split = NamedSharding(rt.mesh, P(rt.axis))
-    whole = NamedSharding(rt.mesh, P())
+    to = NamedSharding(rt.mesh, P(rt.axis) if stacked else P())
+    return jax.tree.map(lambda x: jax.device_put(x, to), tree)
 
-    def put(x):
-        stacked = np.ndim(x) >= 1 and np.shape(x)[0] == rt.num_devices
-        return jax.device_put(x, split if stacked else whole)
 
-    return jax.tree.map(put, tree)
+def place_like(tree, like):
+    """Put ``tree`` on the shardings of ``like``, an already placed pytree
+    of the same structure — a restored checkpoint, a blanked or spliced
+    shard lands exactly where its template lives.  Leaves of a vmap-backend
+    template (one device) pass through unchanged."""
+
+    def put(x, t):
+        s = getattr(t, "sharding", None)
+        return jax.device_put(x, s) if isinstance(s, NamedSharding) else x
+
+    return jax.tree.map(put, tree, like)
 
 
 def resolve(rt: Runtime | None) -> Runtime:

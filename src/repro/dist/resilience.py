@@ -332,7 +332,7 @@ class RecoveryManager:
         if fresh is None:                  # budget exhausted: degrade
             self.dead.add(shard)
             return False
-        healed = _runtime.splice_shard(dt, shard, fresh, rt=self.frame.rt)
+        healed = _runtime.splice_shard(dt, shard, fresh)
         if healed.replica is not None:
             # fail_shard marked the mirror stale (the dead executor's
             # copy died with it); with tracker and rows spliced back
@@ -384,8 +384,7 @@ class RecoveryManager:
             if f.kind == "shard_loss":
                 self.frame = dataclasses.replace(
                     self.frame,
-                    data=_runtime.fail_shard(self.frame.data, f.shard,
-                                             rt=self.frame.rt),
+                    data=_runtime.fail_shard(self.frame.data, f.shard),
                     queue=self._fail_queue_shard(f.shard))
             elif f.kind == "capacity_pressure":
                 self._pressure_divisor = max(2.0, float(f.severity))
@@ -411,12 +410,12 @@ class RecoveryManager:
         q = self.frame.queue
         if q is None:
             return None
-        blanked = _mesh.place(dataclasses.replace(
+        blanked = _mesh.place_like(dataclasses.replace(
             q,
             cols={k: v.at[shard].set(0) for k, v in q.cols.items()},
             valid=q.valid.at[shard].set(False),
             fills=q.fills.at[shard].set(0),
-            count=q.count.at[shard].set(0)), self.frame.rt)
+            count=q.count.at[shard].set(0)), q)
         return table_mod._set_queue_mirror(blanked,
                                            *table_mod.queue_pending(q))
 

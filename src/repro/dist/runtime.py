@@ -120,7 +120,7 @@ class Lineage:
         dist/checkpoint.py) or a version outside the log."""
         from repro.dist import checkpoint as _ckpt
         suffix = self.deltas_since(version)   # validate before touching IO
-        dt = _ckpt.restore_dtable(checkpoint_path, like, rt=rt)
+        dt = _ckpt.restore_dtable(checkpoint_path, like)
         return self._apply(dt, suffix, rt)
 
     def replay(self, num_shards: int,
@@ -163,9 +163,8 @@ class Lineage:
         return self._apply(dt, self.deltas, rt)
 
 
-def fail_shard(dt: _dtable.DistributedTable, shard: int,
-               rt: "_mesh.Runtime | None" = None
-               ) -> _dtable.DistributedTable:
+def fail_shard(dt: _dtable.DistributedTable,
+               shard: int) -> _dtable.DistributedTable:
     """Simulate executor loss: blank the shard's slice of every leaf.
 
     Shapes (and therefore jit caches) are preserved — only the shard's
@@ -173,7 +172,7 @@ def fail_shard(dt: _dtable.DistributedTable, shard: int,
     structures are blanked to their *sentinels* (EMPTY keys, NULL
     pointers, valid=False), not zero: zero is a legal key and a legal row
     id, and a dead shard must answer every lookup with a miss, never a
-    fabricated key-0 match.  The blanked dtable stays placed for ``rt``.
+    fabricated key-0 match.  The blanked dtable keeps ``dt``'s placement.
     """
 
     def kill(leaf, fill):
@@ -223,17 +222,16 @@ def fail_shard(dt: _dtable.DistributedTable, shard: int,
         # bit-identically first.
         out = dataclasses.replace(out, replica=dataclasses.replace(
             dt.replica, version=jnp.asarray(-1, jnp.int32)))
-    return _mesh.place(out, rt)
+    return _mesh.place_like(out, dt)
 
 
 def splice_shard(dt: _dtable.DistributedTable, shard: int,
-                 fresh: _dtable.DistributedTable,
-                 rt: "_mesh.Runtime | None" = None
+                 fresh: _dtable.DistributedTable
                  ) -> _dtable.DistributedTable:
     """Splice one shard's slice of ``fresh`` into ``dt`` (the recovery
-    state machine's final step — DESIGN.md §12).  Leaf shapes and, for
-    ``rt``, placements are untouched, so the spliced dtable re-enters
-    every live jit cache entry.  Raises if the two dtables disagree on
+    state machine's final step — DESIGN.md §12).  Leaf shapes and
+    placements are ``dt``'s, so the spliced dtable re-enters every live
+    jit cache entry.  Raises if the two dtables disagree on
     the global version (a lineage that missed a ``record_append``)."""
     if int(np.asarray(fresh.version)) != int(np.asarray(dt.version)):
         raise ValueError(
@@ -246,7 +244,7 @@ def splice_shard(dt: _dtable.DistributedTable, shard: int,
         return broken.at[shard].set(rebuilt[shard])
 
     table = jax.tree.map(splice, dt.table, fresh.table)
-    return _mesh.place(dataclasses.replace(dt, table=table), rt)
+    return _mesh.place_like(dataclasses.replace(dt, table=table), dt)
 
 
 def rebuild_shard(dt: _dtable.DistributedTable, shard: int,
@@ -263,7 +261,7 @@ def rebuild_shard(dt: _dtable.DistributedTable, shard: int,
     ``record_append``).
     """
     fresh = lineage.replay(dt.num_shards, rt=rt, like=dt)
-    return splice_shard(dt, shard, fresh, rt=rt)
+    return splice_shard(dt, shard, fresh)
 
 
 @dataclasses.dataclass

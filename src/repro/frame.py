@@ -672,8 +672,8 @@ class IndexedFrame:
             kw["capacity"] = int(capacity)
         if max_matches is not None:
             kw["max_matches"] = int(max_matches)
-        return dataclasses.replace(
-            self, data=self._placed(dd.attach_replica(self.data, **kw)))
+        return dataclasses.replace(self, data=dd.place_dtable(
+            dd.attach_replica(self.data, **kw), self.rt))
 
     def refresh_replica(self) -> "IndexedFrame":
         """Re-mirror the current global top-H hot keys at the live
@@ -786,10 +786,9 @@ class IndexedFrame:
         supplies the treedef AND the runtime, exactly as
         ``dist.checkpoint.restore_dtable``)."""
         if like.is_partitioned:
-            data = partition_mod.restore_partitioned(path, like.data,
-                                                    rt=like.rt)
+            data = partition_mod.restore_partitioned(path, like.data)
         elif like.is_distributed:
-            data = _checkpoint().restore_dtable(path, like.data, rt=like.rt)
+            data = _checkpoint().restore_dtable(path, like.data)
         else:
             data = _checkpoint().restore_table(path, like.data)
         return dataclasses.replace(like, data=data)
@@ -848,4 +847,5 @@ class IndexedFrame:
         if t.hot is not None:
             dt = dataclasses.replace(dt, table=dataclasses.replace(
                 dt.table, hot=_dtable().reseed_tracker(t.hot, num_shards)))
+        dt = _dtable().place_dtable(dt, rt_out)
         return IndexedFrame(data=dt, rt=rt_out)

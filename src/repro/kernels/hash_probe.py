@@ -17,9 +17,11 @@ Memory placement (TPU v5e: VMEM and SMEM are MiB, a table is GB):
   * the planes are passed transposed, ``[slots, nb]``.  XLA stores a
     ``[nb, slots]`` int32 plane slot-major on TPU, so the transpose is a
     bitcast (no copy), and the ``(slots, 128)`` window holding bucket
-    ``b`` is one tile-aligned DMA.  ``prev`` is viewed as ``[cap/128,
-    128]`` (also a bitcast) and a hop fetches the 128-lane row holding
-    the pointer.
+    ``b`` is one tile-aligned DMA.  ``prev`` is passed as stored, ``[T,
+    cap]``, and a hop fetches the 128-lane column window holding the
+    pointer for all ``T`` tables — one tile, since a ``[T, cap]`` array is
+    tiled ``(T, 128)`` for small ``T``; reshaping it to ``[T, cap/128,
+    128]`` instead would relayout it on every call.
 
 Per grid step (a tile of at most QUERY_TILE queries):
   1. for each segment, newest -> oldest: one DMA per plane per query whose
@@ -35,8 +37,8 @@ can flag truncation.  Bit-identical to ``ref.fused_lookup_ref``.
 The grid has a leading table axis: the distributed layer's vmap runtime
 stacks shard tables, and Pallas cannot batch an HBM (``pl.ANY``) operand
 itself, so ``fused_lookup_tiles`` carries a ``custom_vmap`` rule that maps
-a stacked snapshot onto that axis (and folds a batch of query sets over
-one table into the query axis).
+a stacked snapshot onto that axis (an unbatched operand is broadcast to
+the batch).
 
 TPU adaptation notes:
   * int64 keys are pre-split into (hi, lo) int32 planes — the TPU VPU has no
@@ -95,10 +97,10 @@ def _fused_lookup_kernel(*refs, num_segments: int, max_matches: int,
 
     refs: bids (SMEM [S * tile], segment-major), qhi, qlo (SMEM [tile]),
     fill (SMEM [1]); per segment the (hi, lo, ptr) planes ``[T, slots,
-    nb]`` and the ``[T, prev_rows, 128]`` prev view (all HBM); outputs
-    rows (SMEM [tile * M], query-major) and last (SMEM [tile]); scratch:
-    the plane windows (VMEM [3, tile, slots, 128]), the prev rows (VMEM
-    [tile, 1, 128]), the per-query chain cursor (SMEM [tile]) and two DMA
+    nb]`` and ``prev`` ``[T, prev_rows * 128]`` (all HBM); outputs rows
+    (SMEM [tile * M], query-major) and last (SMEM [tile]); scratch: the
+    plane windows (VMEM [3, tile, slots, 128]), the prev windows (VMEM
+    [tile, T, 128]), the per-query chain cursor (SMEM [tile]) and two DMA
     semaphores.
     """
     bids_ref, qhi_ref, qlo_ref, fill_ref = refs[:4]
@@ -155,8 +157,11 @@ def _fused_lookup_kernel(*refs, num_segments: int, max_matches: int,
     # -- 2. chain walk over the flat prev array -----------------------------
     def prev_copy(j):
         row = jnp.minimum(cur[j] // LANES, prev_rows - 1)
-        return pltpu.make_async_copy(prev_hbm.at[t, pl.ds(row, 1), :],
-                                     pwin.at[j], sem.at[_HOP])
+        start = pl.multiple_of(row * LANES, LANES)
+        return pltpu.make_async_copy(
+            prev_hbm.at[:, pl.ds(start, LANES)], pwin.at[j], sem.at[_HOP])
+
+    tables = jax.lax.broadcasted_iota(jnp.int32, pwin.shape[1:], 0)
 
     def hop(m, alive):
         def emit(j):
@@ -174,7 +179,7 @@ def _fused_lookup_kernel(*refs, num_segments: int, max_matches: int,
             @pl.when(c >= 0)
             def _():
                 prev_copy(j).wait()
-                nxt = _lane_pick(pwin[j], c % LANES)
+                nxt = _lane_pick(pwin[j], c % LANES, tables == t)
                 cur[j] = jnp.where(nxt < fill, nxt, NULL)  # fill-masked hop
             return n_alive + (cur[j] >= 0).astype(jnp.int32)
 
@@ -211,7 +216,6 @@ def _stacked_call(bids, qhi, qlo, fill, planes, prev, *, max_matches: int,
     if prev.shape[1] % LANES:
         prev = jnp.pad(prev, ((0, 0), (0, LANES - prev.shape[1] % LANES)),
                        constant_values=NULL)
-    prev = prev.reshape(n_tab, -1, LANES)              # a bitcast on TPU
     # Per-tile SMEM operands are [T, nt, 1, width] with squeezed table and
     # tile axes: the (1, width) block spans the last two dims, as Mosaic
     # needs for SMEM blocks narrower than a lane tile.
@@ -225,7 +229,7 @@ def _stacked_call(bids, qhi, qlo, fill, planes, prev, *, max_matches: int,
     qspec = smem(tile)
     kernel = functools.partial(_fused_lookup_kernel, num_segments=s,
                                max_matches=max_matches,
-                               prev_rows=prev.shape[1], tile=tile)
+                               prev_rows=prev.shape[1] // LANES, tile=tile)
     # The kernel is traced with x64 off: under x64 its Python-int indices
     # and loop bounds would lower as int64, which Mosaic rejects.
     with jax.enable_x64(False):
@@ -243,7 +247,7 @@ def _stacked_call(bids, qhi, qlo, fill, planes, prev, *, max_matches: int,
                 jax.ShapeDtypeStruct((n_tab, nt, 1, tile), jnp.int32)),
             scratch_shapes=[
                 pltpu.VMEM((3, tile, slots, LANES), jnp.int32),
-                pltpu.VMEM((tile, 1, LANES), jnp.int32),
+                pltpu.VMEM((tile, n_tab, LANES), jnp.int32),
                 pltpu.SMEM((tile,), jnp.int32),
                 pltpu.SemaphoreType.DMA((2,))],
             interpret=interpret,
@@ -274,14 +278,6 @@ def _lookup_fn(max_matches: int, interpret: bool):
         b_bids, b_qhi, b_qlo, b_fill, b_planes, b_prev = in_batched
         bids, qhi, qlo = (lead(x, b) for x, b in
                           ((bids, b_bids), (qhi, b_qhi), (qlo, b_qlo)))
-        q = qhi.shape[1]
-        if not (b_fill or b_prev or any(b_planes)):
-            # one table, n query sets: fold them into the query axis
-            rows, last = lookup(
-                bids.transpose(1, 0, 2).reshape(bids.shape[1], n * q),
-                qhi.reshape(n * q), qlo.reshape(n * q), fill, planes, prev)
-            return (rows.reshape(n, q, max_matches),
-                    last.reshape(n, q)), (True, True)
         out = _stacked_call(
             bids, qhi, qlo, lead(fill, b_fill),
             tuple(lead(p, b) for p, b in zip(planes, b_planes)),
@@ -307,8 +303,9 @@ def fused_lookup_tiles(bucket_ids, q_hi, q_lo, snapshot,
                   last [Q] int32 — next row id after the walk; >= 0 means
                   the chain was truncated at max_matches)
 
-    VMEM holds ``tile * (3 * slots + 8) * 128 * 4`` bytes of windows
-    whatever the table's size; SMEM holds the query tile and a
+    VMEM holds ``tile * (3 * slots + max(T, 8)) * 128 * 4`` bytes of
+    windows for ``T`` stacked tables (1 unless vmapped), whatever the
+    tables' size; SMEM holds the query tile and a
     ``tile * max_matches`` output block (``_tile_for``).
     """
     assert bucket_ids.shape[1] % QUERY_TILE == 0, bucket_ids.shape

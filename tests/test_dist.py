@@ -221,3 +221,24 @@ def test_mesh_place_puts_each_shard_on_its_device(rng):
             assert leaf.sharding.is_fully_replicated
     tree = {"stacked": jnp.zeros((n, 3)), "scalar": jnp.zeros(())}
     assert mesh.place(tree, mesh.vmap_runtime()) is tree
+
+
+def test_shard_capacity_sized_from_the_routed_share(rng):
+    """A new dtable reserves the capacity class of the per-shard share:
+    a fullest shard a little over the share does not double every
+    shard's planes; skew beyond the class still gets a cover."""
+    from repro.core import hashing
+    from repro.core.table import capacity_class
+    from repro.dist.dtable import shard_capacity
+    assert shard_capacity(256, 270, 64) == capacity_class(256, 64) == 512
+    assert capacity_class(270, 64) == 1024          # the fullest's class
+    assert shard_capacity(256, 700, 64) == 1024     # skew: cover fullest
+    keys = rng.choice(10**9, 1024, replace=False).astype(np.int64)
+    counts = np.bincount(hashing.partition_hash_host(keys, 4), minlength=4)
+    assert counts.max() > 256                 # routing overshoots the share
+    dt = create_distributed({"k": keys, "v": np.ones(1024, np.float32)},
+                            SCH, 4, rows_per_batch=64)
+    assert dt.table.capacity == 512
+    got, valid, _ = lookup(dt, keys[:50], max_matches=1)
+    assert bool(np.asarray(valid).all())
+    np.testing.assert_array_equal(np.asarray(got["k"])[:, 0], keys[:50])

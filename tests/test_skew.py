@@ -440,3 +440,62 @@ def test_same_stream_same_hot_set_on_forced_8_mesh_subprocess():
     proc = _run_forced_8(_SUBPROCESS_SKEW)
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     assert "SKEW_8DEV_OK" in proc.stdout
+
+
+_SUBPROCESS_REPLICA_PLACEMENT = r"""
+import numpy as np, jax
+n = len(jax.devices())
+assert n == 8, jax.devices()
+from repro.core.schema import Schema
+from repro.frame import IndexedFrame
+from repro.dist import mesh
+
+SCH = Schema.of("k", k="int64", v="float32")
+rng = np.random.default_rng(3)
+fr = IndexedFrame.from_columns(
+    {"k": np.arange(64, dtype=np.int64), "v": np.zeros(64, np.float32)},
+    SCH, num_shards=n, rt=mesh.mesh_runtime(n), track_hot=16, reserve=4096)
+# a replica as wide as the mesh: its [H] axis must not be split
+fr = fr.with_replica(capacity=n, max_matches=4)
+traces = [0]
+
+
+@jax.jit
+def site(f, q):
+    traces[0] += 1
+    return f.lookup(q, max_matches=4, op="hybrid")
+
+
+def check(f):
+    for leaf in jax.tree_util.tree_leaves(f.data.replica):
+        assert leaf.sharding.is_fully_replicated, leaf.sharding
+    assert f.data.version.sharding.is_fully_replicated
+    for leaf in jax.tree_util.tree_leaves(f.data.table):
+        assert all(s.data.shape[0] == 1 for s in leaf.addressable_shards)
+
+
+q = np.array([7, 3, 5000], np.int64)
+check(fr)
+site(fr, q)
+for step in range(2):          # each append refreshes the mirror
+    fr = fr.append({"k": np.full(16, 7, np.int64),
+                    "v": rng.random(16).astype(np.float32)})
+    check(fr)
+    ch, vh = site(fr, q)
+    cr, vr = fr.lookup(q, max_matches=4, op="routed")
+    np.testing.assert_array_equal(np.asarray(vh), np.asarray(vr))
+    np.testing.assert_array_equal(np.asarray(ch["v"]), np.asarray(cr["v"]))
+assert traces[0] == 1, traces
+print("REPLICA_PLACEMENT_OK")
+"""
+
+
+@pytest.mark.skipif(NDEV >= 8, reason="in-process mesh tests already "
+                    "run on this topology")
+def test_replica_as_wide_as_mesh_stays_replicated_subprocess():
+    """A hot-key replica with ``capacity == num_devices`` is replicated
+    over the mesh when attached and after every refresh, so the hybrid
+    read site keeps one trace across appends."""
+    proc = _run_forced_8(_SUBPROCESS_REPLICA_PLACEMENT)
+    assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
+    assert "REPLICA_PLACEMENT_OK" in proc.stdout

@@ -33,6 +33,10 @@ RB = 4096                      # rows_per_batch (the facade default)
 SLOTS = hashindex.DEFAULT_SLOTS
 HBM = 16 * 10**9               # one v5e chip
 SCH = Schema.of("k", k="int64", v="float32")
+# Temporaries a lookup may hold whatever the table's size: tile windows,
+# the query batch, the answer.  The bucket planes and ``prev`` reach the
+# kernel as bitcasts, never as copies.
+FIXED_TEMP = 16 << 20
 
 
 @pytest.fixture(scope="module")
@@ -98,36 +102,56 @@ def _table_shapes(rows: int, num_shards: int | None = None) -> IndexedTable:
                         rows_per_batch=RB, layout="row", slots=SLOTS)
 
 
-def _check(compiled, *, kernel: bool):
+def _row_relayout_bytes(rows: int) -> int:
+    """Bytes of the one O(capacity) temporary a read site holds today:
+    the row gather relayouts the flat ``[cap, 3]`` int32 row words into
+    4-word tiles on every call (an open question in PERF.md).  Pinned so
+    that any further per-call copy of the table shows."""
+    return table_mod.capacity_class(rows, RB) * 4 * 4
+
+
+def _check(compiled, *, kernel: bool, max_temp: int | None = None):
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == kernel
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM, total
-    return total
+    if max_temp is not None:
+        assert mem.temp_size_in_bytes <= max_temp, mem.temp_size_in_bytes
+    return mem.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("max_matches", [1, 64])
 def test_fused_lookup_compiles_at_deployment_size(one_chip, max_matches):
-    """The default TPU read path: one table of 2^24 rows on one chip."""
-    snap = _place(snap_mod.strip_data(_table_shapes(ROWS).snapshot),
-                  one_chip)
-    q = jax.ShapeDtypeStruct((4096,), jnp.int64, sharding=one_chip)
-    compiled = ops._fused_kernel_jit.lower(
-        q, snap, max_matches=max_matches, interpret=False).compile()
-    _check(compiled, kernel=True)
+    """The default TPU read path: one table of 2^24 rows on one chip.
+    No temporary grows with the table (same temp bytes at a quarter of
+    the rows)."""
+    temps = []
+    for rows in (ROWS // 4, ROWS):
+        snap = _place(snap_mod.strip_data(_table_shapes(rows).snapshot),
+                      one_chip)
+        q = jax.ShapeDtypeStruct((4096,), jnp.int64, sharding=one_chip)
+        compiled = ops._fused_kernel_jit.lower(
+            q, snap, max_matches=max_matches, interpret=False).compile()
+        temps.append(_check(compiled, kernel=True, max_temp=FIXED_TEMP))
+    assert temps[0] == temps[1], temps
 
 
 def test_fused_lookup_vmapped_over_shards_compiles(one_chip):
-    """The one-chip vmap runtime: 2^24 rows over 4 stacked shards."""
-    snap = _place(snap_mod.strip_data(
-        _table_shapes(ROWS // 4, num_shards=4).snapshot), one_chip)
-    q = jax.ShapeDtypeStruct((4, 1024), jnp.int64, sharding=one_chip)
+    """The one-chip vmap runtime: 2^24 rows over 4 stacked shards.  The
+    stacked ``prev`` and planes are not copied per call (same temp bytes
+    at a quarter of the rows)."""
     look = functools.partial(ops._fused_kernel_jit, max_matches=64,
                              interpret=False)
-    compiled = jax.jit(jax.vmap(look)).lower(q, snap).compile()
-    _check(compiled, kernel=True)
+    temps = []
+    for rows in (ROWS // 4, ROWS):
+        snap = _place(snap_mod.strip_data(
+            _table_shapes(rows // 4, num_shards=4).snapshot), one_chip)
+        q = jax.ShapeDtypeStruct((4, 1024), jnp.int64, sharding=one_chip)
+        compiled = jax.jit(jax.vmap(look)).lower(q, snap).compile()
+        temps.append(_check(compiled, kernel=True, max_temp=FIXED_TEMP))
+    assert temps[0] == temps[1], temps
 
 
 def test_facade_lookup_and_join_compile_at_deployment_size(
@@ -139,12 +163,13 @@ def test_facade_lookup_and_join_compile_at_deployment_size(
     frame = IndexedFrame(data=_place(_table_shapes(ROWS), one_chip))
     q = jax.ShapeDtypeStruct((256,), jnp.int64, sharding=one_chip)
     lookup = jax.jit(lambda fr, k: fr.lookup(k, max_matches=4))
-    _check(lookup.lower(frame, q).compile(), kernel=True)
+    bound = _row_relayout_bytes(ROWS) + FIXED_TEMP
+    _check(lookup.lower(frame, q).compile(), kernel=True, max_temp=bound)
     probe = _place({"k": jax.ShapeDtypeStruct((1 << 16,), jnp.int64),
                     "p": jax.ShapeDtypeStruct((1 << 16,), jnp.int32)},
                    one_chip)
     join = jax.jit(lambda fr, pc: fr.join(pc, "k", max_matches=4))
-    _check(join.lower(frame, probe).compile(), kernel=True)
+    _check(join.lower(frame, probe).compile(), kernel=True, max_temp=bound)
 
 
 def test_arena_ingest_compiles_at_deployment_size(one_chip):
@@ -189,5 +214,28 @@ def test_routed_lookup_compiles_on_four_chip_mesh(topo, kernel_dispatch):
     q = jax.ShapeDtypeStruct((4096,), jnp.int64, sharding=whole)
     compiled = jax.jit(lambda fr, keys: fr.lookup(
         keys, max_matches=64, op="routed")).lower(frame, q).compile()
-    _check(compiled, kernel=True)
+    _check(compiled, kernel=True,
+           max_temp=_row_relayout_bytes(ROWS) + FIXED_TEMP)
     assert "all-to-all" in compiled.as_text()
+
+
+def test_distributed_build_fits_four_chip_mesh(topo):
+    """``create_distributed``'s shard_map build at 2^24 rows per shard.
+    Hash routing leaves the fullest shard a little over the share; the
+    reserved class is still the share's, and the segment build and the
+    flat-data snapshot each fit one chip."""
+    from repro.dist import dtable, mesh
+    cap = dtable.shard_capacity(ROWS, ROWS + 3000, RB)
+    assert cap == table_mod.capacity_class(ROWS, RB) == 2 * ROWS
+    rt = mesh.mesh_runtime(4, devices=topo.devices)
+    split = NamedSharding(rt.mesh, P(rt.axis))
+    col = functools.partial(jax.ShapeDtypeStruct, (4, cap), sharding=split)
+    build = dtable._segment_build_fn(
+        rt, SCH, 0, RB, "row", hashindex.suggest_num_buckets(cap, SLOTS),
+        SLOTS)
+    args = ({"k": col(jnp.int64), "v": col(jnp.float32)}, col(jnp.bool_),
+            col(jnp.int32))
+    _check(build.lower(*args).compile(), kernel=False)
+    seg = _place(jax.eval_shape(build, *args)[0], split)
+    _check(dtable._snapshot_fn(rt, SCH, "row").lower(seg).compile(),
+           kernel=False)
