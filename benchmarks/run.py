@@ -15,8 +15,9 @@ import time
 # Modules import lazily so one broken dependency cannot take down the whole
 # harness.  lookup_path, fault_tolerance, and scalability additionally write
 # the committed artifacts BENCH_lookup.json / BENCH_dist.json /
-# BENCH_scale.json at the repo root (scalability's mesh sweep forces an
-# 8-device host topology in a subprocess); append_read_latency and
+# BENCH_scale.json at the repo root (scalability's mesh sweep runs in
+# process over 4 of jax.devices(); on the CPU the caller forces them with
+# XLA_FLAGS=--xla_force_host_platform_device_count=4); append_read_latency and
 # write_throughput share BENCH_append.json (Fig 9 + Fig 10, the arena
 # write path before/after — DESIGN.md §4).
 MODULES = {
