@@ -41,6 +41,7 @@ MODULES = [
     "repro.dist.resilience",
     "repro.serving",
     "repro.serving.query_engine",
+    "repro.obs",
 ]
 
 OUT_NAME = "api_surface.txt"

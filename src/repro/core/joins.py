@@ -22,6 +22,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import hashindex as hix
 from repro.core.pointers import NULL_PTR
 from repro.core.table import IndexedTable
@@ -69,9 +70,10 @@ def indexed_lookup(table: IndexedTable, keys, *, max_matches: int,
     ``fused=False`` keeps the segment-looped reference path for parity
     sweeps."""
     check_max_matches(max_matches)
-    keys = as_int64_keys(keys)
-    rids, _ = table.lookup(keys, max_matches, fused=fused)
-    valid = rids != NULL_PTR
+    with obs.span("joins.probe"):
+        keys = as_int64_keys(keys)
+        rids, _ = table.lookup(keys, max_matches, fused=fused)
+        valid = rids != NULL_PTR
     cols = table.gather_rows(jnp.maximum(rids, 0), names=names, fused=fused)
     return cols, valid
 

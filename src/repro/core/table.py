@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import hashindex as hix
 from repro.core import hashing
 from repro.core import snapshot as snap_mod
@@ -218,8 +219,9 @@ class IndexedTable:
             return d
         d = getattr(self, "_flatdata", None)
         if d is None:
-            d = snap_mod.flat_data_from_segments(self.segments, self.schema,
-                                                 self.layout)
+            with jax.named_scope("row_words"):
+                d = snap_mod.flat_data_from_segments(
+                    self.segments, self.schema, self.layout)
             leaves = jax.tree_util.tree_leaves(d)
             if not any(isinstance(a, jax.core.Tracer) for a in leaves):
                 object.__setattr__(self, "_flatdata", d)
@@ -297,21 +299,22 @@ class IndexedTable:
         """Decode rows for global row ids (zeros where rid out of range)."""
         if not fused:
             return self.gather_rows_ref(rids, names=names)
-        data = self._flat_data()
-        rids = jnp.asarray(rids, PTR_DTYPE)
-        # fill-masked: reserved-but-unwritten arena lanes never decode
-        # (with donation they may alias retired buffers — DESIGN.md §4)
-        in_range = (rids >= 0) & (rids < self.snapshot.fill)
-        safe = jnp.clip(rids, 0, self.capacity - 1)
-        if self.layout == "row":
-            flat = jnp.where(in_range[..., None], data[safe], 0)
-            return self.schema.decode_rows(flat, names=names)
-        out = {}
-        for name in (names or self.schema.names):
-            col = self.schema.column(name)
-            out[name] = jnp.where(in_range, data[name][safe],
-                                  jnp.zeros((), col.jnp_dtype))
-        return out
+        with obs.span("joins.decode"), jax.named_scope("row_decode"):
+            data = self._flat_data()
+            rids = jnp.asarray(rids, PTR_DTYPE)
+            # fill-masked: reserved-but-unwritten arena lanes never decode
+            # (with donation they may alias retired buffers — DESIGN.md §4)
+            in_range = (rids >= 0) & (rids < self.snapshot.fill)
+            safe = jnp.clip(rids, 0, self.capacity - 1)
+            if self.layout == "row":
+                flat = jnp.where(in_range[..., None], data[safe], 0)
+                return self.schema.decode_rows(flat, names=names)
+            out = {}
+            for name in (names or self.schema.names):
+                col = self.schema.column(name)
+                out[name] = jnp.where(in_range, data[name][safe],
+                                      jnp.zeros((), col.jnp_dtype))
+            return out
 
     def gather_rows_ref(self, rids, names=None) -> dict:
         """Segment-looped reference: one masked pass per segment."""
@@ -1291,35 +1294,39 @@ def _flush_core(state, parent_blocks, queue: AppendQueue, *, schema, layout,
     QUEUE_TRACES["flush"] += 1
     lanes, lane_rows = queue.lanes, queue.lane_rows
     d = lanes * lane_rows
-    occ = jnp.arange(lanes, dtype=jnp.int32) < queue.count       # [lanes]
-    valid_flat = (queue.valid & occ[:, None]).reshape(d)
-    cols_flat = {k: v.reshape((d,) + v.shape[2:])
-                 for k, v in queue.cols.items()}
-    keys = jnp.where(valid_flat, jnp.asarray(cols_flat[schema.key],
-                                             jnp.int64), EMPTY_KEY)
-    nv = jnp.sum(queue.fills * occ.astype(jnp.int32))
-    room = jnp.int32(cap) - state["fill"]
-    fits = nv <= room
-    ovf = _arena_fits_core(state["bk"], keys, valid_flat)
-    ok = fits & (ovf == 0)
-    if axis is None:
-        ok = ok & (nv > 0)
-    else:
-        bad = jax.lax.psum((~ok).astype(jnp.int32), axis)
-        total = jax.lax.psum(nv, axis)
-        ok = (bad == 0) & (total > 0)
+    with jax.named_scope("flush_lanes"):
+        occ = jnp.arange(lanes, dtype=jnp.int32) < queue.count   # [lanes]
+        valid_flat = (queue.valid & occ[:, None]).reshape(d)
+        cols_flat = {k: v.reshape((d,) + v.shape[2:])
+                     for k, v in queue.cols.items()}
+        keys = jnp.where(valid_flat, jnp.asarray(cols_flat[schema.key],
+                                                 jnp.int64), EMPTY_KEY)
+        nv = jnp.sum(queue.fills * occ.astype(jnp.int32))
+    with jax.named_scope("flush_preflight"):
+        room = jnp.int32(cap) - state["fill"]
+        fits = nv <= room
+        ovf = _arena_fits_core(state["bk"], keys, valid_flat)
+        ok = fits & (ovf == 0)
+        if axis is None:
+            ok = ok & (nv > 0)
+        else:
+            bad = jax.lax.psum((~ok).astype(jnp.int32), axis)
+            total = jax.lax.psum(nv, axis)
+            ok = (bad == 0) & (total > 0)
     gated_valid = valid_flat & ok
     version = state["version"]
-    out, _ = _ingest_arrays(
-        state, parent_blocks, cols_flat, gated_valid, schema=schema,
-        layout=layout, rb=rb, bucket_counts=bucket_counts, slots=slots)
+    with jax.named_scope("flush_ingest"):
+        out, _ = _ingest_arrays(
+            state, parent_blocks, cols_flat, gated_valid, schema=schema,
+            layout=layout, rb=rb, bucket_counts=bucket_counts, slots=slots)
     # _ingest_arrays bumps unconditionally; a held flush must not.
     out["version"] = version + ok.astype(jnp.int32)
-    ring = dataclasses.replace(
-        queue,
-        valid=queue.valid & ~ok,
-        fills=jnp.where(ok, 0, queue.fills),
-        count=jnp.where(ok, 0, queue.count))
+    with jax.named_scope("flush_ring"):
+        ring = dataclasses.replace(
+            queue,
+            valid=queue.valid & ~ok,
+            fills=jnp.where(ok, 0, queue.fills),
+            count=jnp.where(ok, 0, queue.count))
     return out, ring, ok
 
 
@@ -1381,7 +1388,9 @@ def flush_queue(table: IndexedTable, queue: AppendQueue, *,
                        slots=table.slots,
                        cap=tail.row_base + tail.capacity)
     child = _reassemble(table, out)
-    if bool(jax.device_get(ok)):              # THE one host sync per flush
+    with obs.span("table.flush_check"):
+        landed = bool(jax.device_get(ok))     # THE one host sync per flush
+    if landed:
         return child, _set_queue_mirror(ring, 0, 0), False
     # held: child is content-identical to the parent (all scatters
     # dropped, version post-corrected); under donation the PARENT buffers

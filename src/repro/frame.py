@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import hashing
 from repro.core import joins
 from repro.core import partition as partition_mod
@@ -309,21 +310,22 @@ class IndexedFrame:
                     ) -> planner_mod.Physical:
         """The physical operator ``lookup`` would run for this query batch
         (rules L1-L4) — ``.explain()`` on the result names the rule."""
-        if op == "auto":
-            p = self._planner(planner, max_matches)
-            phys = p.physical_lookup(self.data, int(jnp.shape(keys)[0]),
-                                     keys=keys)
-        elif self.is_partitioned:
-            raise ValueError(
-                f"a partitioned frame picks the per-partition flavor "
-                f"itself (rule P1); op must be 'auto', got {op!r}")
-        else:
-            phys = self._forced_plan(op, _LOOKUP_OPS,
-                                     {"local": "IndexedLookup",
-                                      "bcast": "BroadcastLookup",
-                                      "routed": "RoutedLookup",
-                                      "hybrid": "HybridLookup"})
-        return self._annotate(phys, keys)
+        with obs.span("frame.plan", kind="lookup"):
+            if op == "auto":
+                p = self._planner(planner, max_matches)
+                phys = p.physical_lookup(self.data, int(jnp.shape(keys)[0]),
+                                         keys=keys)
+            elif self.is_partitioned:
+                raise ValueError(
+                    f"a partitioned frame picks the per-partition flavor "
+                    f"itself (rule P1); op must be 'auto', got {op!r}")
+            else:
+                phys = self._forced_plan(op, _LOOKUP_OPS,
+                                         {"local": "IndexedLookup",
+                                          "bcast": "BroadcastLookup",
+                                          "routed": "RoutedLookup",
+                                          "hybrid": "HybridLookup"})
+            return self._annotate(phys, keys)
 
     def lookup(self, keys, *, max_matches: int = 64, names=None,
                op: str = "auto",
@@ -365,22 +367,23 @@ class IndexedFrame:
                   ) -> planner_mod.Physical:
         """The physical operator ``join`` would run for this probe side
         (rules J1-J4)."""
-        if op == "auto":
-            p = self._planner(planner, max_matches)
-            phys = p.physical_join(self.data,
-                                   int(jnp.shape(probe_cols[on])[0]),
-                                   keys=probe_cols[on])
-        elif self.is_partitioned:
-            raise ValueError(
-                f"a partitioned frame picks the per-partition flavor "
-                f"itself (rule P3); op must be 'auto', got {op!r}")
-        else:
-            phys = self._forced_plan(op, _JOIN_OPS,
-                                     {"local": "IndexedJoin",
-                                      "bcast": "BroadcastJoin",
-                                      "shuffle": "ShuffleJoin",
-                                      "hybrid": "HybridJoin"})
-        return self._annotate(phys, probe_cols[on])
+        with obs.span("frame.plan", kind="join"):
+            if op == "auto":
+                p = self._planner(planner, max_matches)
+                phys = p.physical_join(self.data,
+                                       int(jnp.shape(probe_cols[on])[0]),
+                                       keys=probe_cols[on])
+            elif self.is_partitioned:
+                raise ValueError(
+                    f"a partitioned frame picks the per-partition flavor "
+                    f"itself (rule P3); op must be 'auto', got {op!r}")
+            else:
+                phys = self._forced_plan(op, _JOIN_OPS,
+                                         {"local": "IndexedJoin",
+                                          "bcast": "BroadcastJoin",
+                                          "shuffle": "ShuffleJoin",
+                                          "hybrid": "HybridJoin"})
+            return self._annotate(phys, probe_cols[on])
 
     def join(self, probe_cols: dict, on: str, *, max_matches: int = 64,
              names=None, op: str = "auto",
@@ -394,28 +397,30 @@ class IndexedFrame:
         like every other flavor.
         """
         joins.check_max_matches(max_matches)
-        keys = joins.as_int64_keys(probe_cols[on])
-        kind = self.plan_join(probe_cols, on, max_matches=max_matches,
-                              op=op, planner=planner).kind
-        if kind == "PartitionedJoin":
-            return partition_mod.join_partitioned(
-                self.data, probe_cols, on, max_matches=max_matches,
-                names=names, rt=self.rt, routed_threshold=self._planner(
-                    planner, max_matches).routed_threshold)
-        if kind == "IndexedJoin":
-            return joins.indexed_join(self.data, probe_cols, on,
-                                      max_matches=max_matches, names=names)
-        if kind == "BroadcastJoin":
-            return _dtable().indexed_join_bcast(
-                self.data, probe_cols, on, max_matches, names=names,
-                rt=self.rt)
-        if kind == "HybridJoin":
-            return _dtable().indexed_join_hybrid(
+        with obs.span("frame.join", rows=len(probe_cols[on])):
+            keys = joins.as_int64_keys(probe_cols[on])
+            kind = self.plan_join(probe_cols, on, max_matches=max_matches,
+                                  op=op, planner=planner).kind
+            if kind == "PartitionedJoin":
+                return partition_mod.join_partitioned(
+                    self.data, probe_cols, on, max_matches=max_matches,
+                    names=names, rt=self.rt, routed_threshold=self._planner(
+                        planner, max_matches).routed_threshold)
+            if kind == "IndexedJoin":
+                return joins.indexed_join(self.data, probe_cols, on,
+                                          max_matches=max_matches,
+                                          names=names)
+            if kind == "BroadcastJoin":
+                return _dtable().indexed_join_bcast(
+                    self.data, probe_cols, on, max_matches, names=names,
+                    rt=self.rt)
+            if kind == "HybridJoin":
+                return _dtable().indexed_join_hybrid(
+                    self.data, probe_cols, on, max_matches=max_matches,
+                    names=names, rt=self.rt)
+            return _dtable().indexed_join_routed(
                 self.data, probe_cols, on, max_matches=max_matches,
                 names=names, rt=self.rt)
-        return _dtable().indexed_join_routed(
-            self.data, probe_cols, on, max_matches=max_matches, names=names,
-            rt=self.rt)
 
     # -- writes: MVCC appends, compaction -------------------------------------
 

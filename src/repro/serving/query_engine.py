@@ -28,8 +28,8 @@ paper's dataframe operators instead of token decode:
   (``frame.supervised(...)``) instead of a bare frame and every batch
   runs through the PR-6 self-healing read path: the engine serves
   traffic mid-heal (tests/test_query_engine.py).
-* **SLO accounting** — per-request latency (submit -> answer ready) and
-  write visibility lag feed ``latency_summary()`` (p50/p99/mean);
+* **SLO accounting** — per-request latency (submit -> answer on the
+  host) and write visibility lag feed ``latency_summary()`` (p50/p99/mean);
   ``benchmarks/serve.py`` sweeps a QPS × write-rate grid into
   ``BENCH_serve.json``.
 
@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import joins
 from repro.core import partition as partition_mod
 from repro.core import table as table_mod
@@ -111,9 +112,10 @@ class QueryRequest:
     on: str | None
     max_matches: int
     t_submit: float
-    t_done: float | None = None
+    t_done: float | None = None    # the answer is on the host
     version: int | None = None     # MVCC version the answer was read at
     bucket: int | None = None
+    batch: int | None = None       # the engine batch that served it
     result: tuple | None = None    # lookup: (cols, valid); join: 3-tuple
 
     @property
@@ -451,27 +453,39 @@ class QueryEngine:
         key = self._batch_key(batch[0])
         n = sum(r.size for r in batch)
         bucket = pick_bucket(n, self.ladder)
+        bid = self.stats.batches
         self.stats.batches += 1
         self.stats.batched_keys += n
         self.stats.padded_lanes += bucket - n
         self._bucket_use.add((key, bucket))
-        if batch[0].kind == "lookup":
-            out = self._exec_lookup(key, batch, n, bucket)
-        else:
-            out = self._exec_join(key, batch, n, bucket)
-        t_done = time.perf_counter()
-        off = 0
         for r in batch:
-            sl = slice(off, off + r.size)
-            r.result = tuple(
-                {k: np.asarray(v[sl]) for k, v in part.items()}
-                if isinstance(part, dict) else np.asarray(part[sl])
-                for part in out)
-            r.bucket = bucket
-            r.version = self._version_host
+            r.batch = bid
+        # admission: padding, the key transfer and the read site's
+        # dispatch; its engine.wait child is the wait for the device
+        with obs.span("engine.admit", batch=bid, keys=n, bucket=bucket):
+            if batch[0].kind == "lookup":
+                out = self._exec_lookup(key, batch, n, bucket)
+            else:
+                out = self._exec_join(key, batch, n, bucket)
+        # delivery: each answer cut out of the batch and copied to the host
+        with obs.span("engine.deliver", batch=bid, requests=len(batch)):
+            off = 0
+            for r in batch:
+                sl = slice(off, off + r.size)
+                r.result = tuple(
+                    {k: np.asarray(v[sl]) for k, v in part.items()}
+                    if isinstance(part, dict) else np.asarray(part[sl])
+                    for part in out)
+                r.bucket = bucket
+                r.version = self._version_host
+                off += r.size
+            t_done = time.perf_counter()
+            copies = sum(len(p) if isinstance(p, dict) else 1 for p in out)
+            obs.count("engine.answered", len(batch))
+            obs.count("engine.host_copies", copies * len(batch))
+        for r in batch:
             r.t_done = t_done
             self.stats.read_latencies_s.append(r.latency_s)
-            off += r.size
         return batch
 
     def _exec_lookup(self, skey, batch, n, bucket):
@@ -491,7 +505,8 @@ class QueryEngine:
         else:
             fn, _ = self._site(skey)
             cols, valid = fn(self._frame, jnp.asarray(padded))
-        jax.block_until_ready(valid)
+        with obs.span("engine.wait", batch=batch[0].batch):
+            jax.block_until_ready(valid)
         return cols, valid
 
     def _exec_join(self, skey, batch, n, bucket):
@@ -517,7 +532,8 @@ class QueryEngine:
             fn, _ = self._site(skey)
             bcols, pcols, valid = fn(
                 self._frame, {k: jnp.asarray(v) for k, v in padded.items()})
-        jax.block_until_ready(valid)
+        with obs.span("engine.wait", batch=batch[0].batch):
+            jax.block_until_ready(valid)
         return bcols, pcols, valid
 
     # -- write interleaving ----------------------------------------------------
@@ -547,24 +563,25 @@ class QueryEngine:
         self.stats.write_latencies_s.append(w.latency_s)
 
     def _stage_write(self, w: WriteRequest):
-        if self._partitioned:
-            # no frame-level ring on partitioned frames (supervised or
-            # not): every write is a routed direct append, its own
-            # version bump — the twin replay stays bit-identical because
-            # write_log records each as its own group
-            self._append_direct(w)
-            return
-        try:
-            self._enqueue(w.cols, w.valid)
-        except table_mod.QueueOverflow:
-            self.flush()                       # ring-full: flush, retry
+        with obs.span("engine.stage", request=w.req_id):
+            if self._partitioned:
+                # no frame-level ring on partitioned frames (supervised
+                # or not): every write is a routed direct append, its own
+                # version bump — the twin replay stays bit-identical
+                # because write_log records each as its own group
+                self._append_direct(w)
+                return
             try:
                 self._enqueue(w.cols, w.valid)
             except table_mod.QueueOverflow:
-                self._append_direct(w)
-                return
-        w.t_staged = time.perf_counter()
-        self._staged.append(w)
+                self.flush()                   # ring-full: flush, retry
+                try:
+                    self._enqueue(w.cols, w.valid)
+                except table_mod.QueueOverflow:
+                    self._append_direct(w)
+                    return
+            w.t_staged = time.perf_counter()
+            self._staged.append(w)
 
     def flush(self):
         """Land the staged ring: ONE fused ingest, ONE version bump for
@@ -572,22 +589,24 @@ class QueryEngine:
         ``write_log`` so a twin can replay the interleaving."""
         if not self._staged:
             return
-        if self._mgr is not None:
-            self._mgr.flush()
-        else:
-            self._frame = self._frame.flush(donate=self.flush_donate)
-        self._version_host += 1
-        t = time.perf_counter()
-        self.write_log.append({
-            "version": self._version_host,
-            "writes": [(w.cols, w.valid) for w in self._staged]})
-        for w in self._staged:
-            w.t_visible = t
-            w.version = self._version_host
-            self.stats.write_latencies_s.append(w.latency_s)
-        self._staged.clear()
-        self.stats.flushes += 1
-        self._ticks_since_flush = 0
+        with obs.span("engine.flush", version=self._version_host + 1,
+                      deltas=len(self._staged)):
+            if self._mgr is not None:
+                self._mgr.flush()
+            else:
+                self._frame = self._frame.flush(donate=self.flush_donate)
+            self._version_host += 1
+            t = time.perf_counter()
+            self.write_log.append({
+                "version": self._version_host,
+                "writes": [(w.cols, w.valid) for w in self._staged]})
+            for w in self._staged:
+                w.t_visible = t
+                w.version = self._version_host
+                self.stats.write_latencies_s.append(w.latency_s)
+            self._staged.clear()
+            self.stats.flushes += 1
+            self._ticks_since_flush = 0
 
     # -- the scheduler tick ----------------------------------------------------
 
