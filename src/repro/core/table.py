@@ -301,19 +301,13 @@ class IndexedTable:
             return self.gather_rows_ref(rids, names=names)
         with obs.span("joins.decode"), jax.named_scope("row_decode"):
             data = self._flat_data()
-            rids = jnp.asarray(rids, PTR_DTYPE)
-            # fill-masked: reserved-but-unwritten arena lanes never decode
-            # (with donation they may alias retired buffers — DESIGN.md §4)
-            in_range = (rids >= 0) & (rids < self.snapshot.fill)
-            safe = jnp.clip(rids, 0, self.capacity - 1)
-            if self.layout == "row":
-                flat = jnp.where(in_range[..., None], data[safe], 0)
-                return self.schema.decode_rows(flat, names=names)
-            out = {}
-            for name in (names or self.schema.names):
-                col = self.schema.column(name)
-                out[name] = jnp.where(in_range, data[name][safe],
-                                      jnp.zeros((), col.jnp_dtype))
+            compiled = _decode_rows._cache_size()
+            out = _decode_rows(data, jnp.asarray(rids, PTR_DTYPE),
+                               self.snapshot.fill, schema=self.schema,
+                               names=tuple(names or self.schema.names),
+                               layout=self.layout)
+            if _decode_rows._cache_size() > compiled:
+                obs.count("joins.decode_compiles")
             return out
 
     def gather_rows_ref(self, rids, names=None) -> dict:
@@ -355,6 +349,27 @@ class IndexedTable:
             parts.append(vals)
             valid.append(seg.valid)
         return jnp.concatenate(parts), jnp.concatenate(valid)
+
+
+@partial(jax.jit, static_argnames=("schema", "names", "layout"))
+def _decode_rows(data, rids, fill, *, schema, names, layout) -> dict:
+    """The fused row decode as one program: fill mask, clip, gather, zero
+    fill and word decode.  An eager ``gather_rows`` dispatches it once
+    (one compile per schema, names, layout, capacity and ``rids`` shape);
+    under an outer ``jit``/``vmap``/``shard_map`` it inlines into the
+    caller's program.  ``data`` is the flat data (``_flat_data``), so its
+    leading dimension is the capacity."""
+    # fill-masked: reserved-but-unwritten arena lanes never decode
+    # (with donation they may alias retired buffers — DESIGN.md §4)
+    in_range = (rids >= 0) & (rids < fill)
+    capacity = jax.tree_util.tree_leaves(data)[0].shape[0]
+    safe = jnp.clip(rids, 0, capacity - 1)
+    if layout == "row":
+        flat = jnp.where(in_range[..., None], data[safe], 0)
+        return schema.decode_rows(flat, names=names)
+    return {name: jnp.where(in_range, data[name][safe],
+                            jnp.zeros((), data[name].dtype))
+            for name in names}
 
 
 # ---------------------------------------------------------------------------

@@ -17,24 +17,30 @@ from repro.core.hashindex import EMPTY_KEY
 from repro.kernels import ops
 
 SCH = Schema.of("k", k="int64", v="float32", tag="int32")
+# Every column width the row codec has: int32, int64, float32, float64.
+WIDE = Schema.of("k", k="int64", v="float32", tag="int32", x="float64")
+
+
+def _cols(rng, n, key_range, schema=SCH, tag_base=0):
+    cols = {"k": rng.integers(0, key_range, n).astype(np.int64),
+            "v": rng.random(n).astype(np.float32),
+            "tag": np.arange(n, dtype=np.int32) + tag_base}
+    if "x" in schema.names:
+        cols["x"] = rng.standard_normal(n)
+    return cols
 
 
 def _table(rng, n_base, n_appends, layout, key_range=60, rows_per_batch=64,
-           append_rows=37, mode="segment"):
+           append_rows=37, mode="segment", schema=SCH):
     """``mode="segment"`` (default here) grows one delta segment per
     append — the multi-segment machinery these sweeps exercise;
     ``mode="arena"`` lands appends in the reserved tail (DESIGN.md §4)."""
-    cols = {"k": rng.integers(0, key_range, n_base).astype(np.int64),
-            "v": rng.random(n_base).astype(np.float32),
-            "tag": np.arange(n_base, dtype=np.int32)}
-    t = create_index(cols, SCH, rows_per_batch=rows_per_batch, layout=layout,
+    t = create_index(_cols(rng, n_base, key_range, schema), schema,
+                     rows_per_batch=rows_per_batch, layout=layout,
                      reserve=0 if mode == "segment" else None)
     for i in range(n_appends):
-        extra = {"k": rng.integers(0, key_range, append_rows)
-                 .astype(np.int64),
-                 "v": rng.random(append_rows).astype(np.float32),
-                 "tag": np.arange(append_rows, dtype=np.int32)
-                 + 1000 * (i + 1)}
+        extra = _cols(rng, append_rows, key_range, schema,
+                      tag_base=1000 * (i + 1))
         t = append(t, extra, mode=mode)
     return t
 
@@ -70,22 +76,32 @@ def test_fused_lookup_parity_sweep(rng, layout, n_appends, mode):
         np.testing.assert_array_equal(np.asarray(tf), np.asarray(tr))
 
 
+def _assert_same_cols(got, want):
+    assert set(got) == set(want)
+    for name in want:
+        assert got[name].dtype == want[name].dtype, name
+        np.testing.assert_array_equal(np.asarray(got[name]),
+                                      np.asarray(want[name]))
+
+
+@pytest.mark.parametrize("schema", [SCH, WIDE], ids=["narrow", "wide"])
 @pytest.mark.parametrize("layout", ["row", "columnar"])
-def test_fused_gather_and_probe_parity(rng, layout):
-    t = _table(rng, 250, 3, layout)
+def test_fused_gather_and_probe_parity(rng, layout, schema):
+    t = _table(rng, 250, 3, layout, schema=schema)
     q = _queries(rng, 60)
     np.testing.assert_array_equal(np.asarray(t.probe_latest(q)),
                                   np.asarray(t.probe_latest_ref(q)))
     rids, _ = t.lookup(q, 6)
     safe = jnp.maximum(rids, 0)
-    gf = t.gather_rows(safe)
-    gr = t.gather_rows_ref(safe)
-    for name in gf:
-        np.testing.assert_array_equal(np.asarray(gf[name]),
-                                      np.asarray(gr[name]))
-    # gather_prev parity incl. NULL and out-of-range ids
-    probe_ids = jnp.asarray([-1, 0, 5, t.capacity - 1, t.capacity, 10**6],
-                            jnp.int32)
+    _assert_same_cols(t.gather_rows(safe), t.gather_rows_ref(safe))
+    # NULL, fill-edge and out-of-range ids decode as the reference does
+    fill = int(t.fill)
+    probe_ids = jnp.asarray([-1, 0, 5, fill - 1, fill, t.capacity - 1,
+                             t.capacity, 10**6], jnp.int32)
+    _assert_same_cols(t.gather_rows(probe_ids), t.gather_rows_ref(probe_ids))
+    subset = ("tag", "x") if "x" in schema.names else ("tag", "k")
+    _assert_same_cols(t.gather_rows(probe_ids, names=subset),
+                      t.gather_rows_ref(probe_ids, names=subset))
     np.testing.assert_array_equal(np.asarray(t.gather_prev(probe_ids)),
                                   np.asarray(t.gather_prev_ref(probe_ids)))
 
@@ -179,6 +195,55 @@ def test_snapshot_eager_probe_side_lazy_data(rng):
     td = t.with_flat_data()                     # explicit materialization
     assert td is not t and td.snapshot.data is not None
     assert td.with_flat_data() is td            # no-op once materialized
+
+
+@pytest.mark.parametrize("layout", ["row", "columnar"])
+def test_eager_decode_compiles_once(rng, layout, tmp_path):
+    """The eager fused decode is one cached program: under a profile, five
+    reads, an append that stays in its capacity class and five more reads
+    compile it once (``joins.decode_compiles``).  Reads leave the pytree
+    alone and cache the flat data on the host; under ``jit`` and ``vmap``
+    the same decode inlines and answers as the eager one does."""
+    import jax
+    from repro import obs
+    # column names of its own, so no earlier test has compiled this decode
+    sch = Schema.of("kc", kc="int64", vc="float32", tc="int32", xc="float64")
+    n = 90
+    cols = {"kc": rng.integers(0, 40, n).astype(np.int64),
+            "vc": rng.random(n).astype(np.float32),
+            "tc": np.arange(n, dtype=np.int32),
+            "xc": rng.standard_normal(n)}
+    t = create_index(cols, sch, rows_per_batch=32, layout=layout)
+    rids = jnp.asarray([-1, 0, 7, n - 1, n, t.capacity - 1, t.capacity, 3],
+                       jnp.int32)
+    treedef = jax.tree_util.tree_structure(t)
+    obs.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eager = [jax.block_until_ready(t.gather_rows(rids))
+                 for _ in range(5)]
+        t2 = append(t, {c: v[:4] for c, v in cols.items()})
+        assert t2.capacity == t.capacity and t2.num_segments == 1
+        eager2 = [jax.block_until_ready(t2.gather_rows(rids))
+                  for _ in range(5)]
+    finally:
+        jax.profiler.stop_trace()
+    counters = obs.profiled()["counters"]
+    obs.reset()
+    assert counters == {"joins.decode_compiles": 1}
+    assert t.snapshot.data is None                  # no pytree mutation
+    assert jax.tree_util.tree_structure(t) == treedef
+    assert getattr(t, "_flatdata", None) is not None
+    _assert_same_cols(eager[0], t.gather_rows_ref(rids))
+    _assert_same_cols(eager2[-1], t2.gather_rows_ref(rids))
+    site = jax.jit(lambda tb, r: tb.gather_rows(r))
+    _assert_same_cols(site(t, rids), eager[0])
+    _assert_same_cols(site(t.with_flat_data(), rids), eager[0])
+    stacked = jnp.stack([rids, rids[::-1]])
+    batched = jax.vmap(t.gather_rows)(stacked)
+    _assert_same_cols({c: v[0] for c, v in batched.items()}, eager[0])
+    _assert_same_cols({c: v[1] for c, v in batched.items()},
+                      t.gather_rows(rids[::-1]))
 
 
 def test_flatview_mixed_bucket_counts(rng):
